@@ -1,5 +1,3 @@
 """repro — QuantEase (Behdin et al., 2023) as a production JAX framework."""
 
-from repro import compat as _compat  # noqa: F401 — jax version shims (side effects)
-
 __version__ = "0.1.0"

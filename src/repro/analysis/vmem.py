@@ -38,9 +38,9 @@ from repro.analysis.framework import Finding, Project, call_name, dotted_name, r
 
 __all__ = ["check_vmem_gates", "check_gate_formulas"]
 
-# VMEM budget the gates enforce (kernels/ops.py leaves ~4 MiB headroom
-# under the ~16 MiB/core VMEM).
-_BUDGET = 12 * 1024 * 1024
+# VMEM budget the gates enforce: kernels/ops._VMEM_BUDGET, restated (the
+# 96 MiB scoped limit asked of Mosaic less 16 MiB of headroom).
+_BUDGET = 80 * 1024 * 1024
 
 
 def _is_gate_name(name: str) -> bool:
@@ -178,13 +178,22 @@ def check_gate_formulas() -> list:
             )
         )
 
+    # Pipelined blocks count twice (double buffering), scratch and
+    # in-kernel temporaries once.
     def fused_bytes(p_pad, bsz, dtype, tq):
-        sig = bsz * p_pad * (2 if dtype == "bfloat16" else 4)
-        return p_pad * tq * 4 + sig + 7 * bsz * tq * 4
+        cd = 2 if dtype == "bfloat16" else 4
+        blocks = p_pad * tq * 4 + bsz * p_pad * cd + bsz * bsz * 4 + 7 * bsz * tq * 4
+        temps = p_pad * tq * 4 + (p_pad * tq * 2 if cd == 2 else 0)
+        return 2 * blocks + temps
 
     def outlier_bytes(p_pad, bsz, dtype, tq):
         cd = 2 if dtype == "bfloat16" else 4
-        return 2 * p_pad * tq * 4 + 2 * bsz * p_pad * cd + 8 * bsz * tq * 4
+        blocks = (
+            2 * p_pad * tq * 4 + 2 * bsz * p_pad * cd + bsz * bsz * 4
+            + 8 * bsz * tq * 4
+        )
+        temps = 2 * p_pad * tq * 4 + (p_pad * tq * 2 if cd == 2 else 0)
+        return 2 * blocks + temps
 
     for p, bsz, dtype in _iter_solver_shapes():
         for gate_name, bytes_fn in (

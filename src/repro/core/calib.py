@@ -40,13 +40,12 @@ def _sharded_gram_fn(mesh, axis: str):
     """One cached shard_mapped executable per (mesh, axis) — the capture
     pass calls this per linear per chunk, so a fresh wrapper per call would
     retrace every time."""
-    from jax.experimental.shard_map import shard_map
 
     def local(xl):
         return jax.lax.psum(xl.T @ xl, axis)
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             local,
             mesh=mesh,
             in_specs=PartitionSpec(axis, None),

@@ -66,13 +66,14 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import warnings
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.calib import damp_sigma
-from repro.core.quantease import _quant_cols, quantease_quantize
+from repro.core.quantease import _quant_cols, fp32_matmuls, quantease_quantize
 from repro.quant import GridSpec, compute_grid_excluding_outliers
 
 __all__ = ["OutlierResult", "outlier_quantease", "top_s_mask", "power_lambda_max"]
@@ -166,6 +167,7 @@ def _project_columns(a: jax.Array, n_cols: int) -> jax.Array:
         "use_kernel", "matmul_dtype", "track_objective", "engine", "lam_iters",
     ),
 )
+@fp32_matmuls
 def outlier_quantease(
     w: jax.Array,
     sigma: jax.Array,
@@ -411,6 +413,11 @@ def _outlier_fused_2d(
         from repro.kernels import ops as kops
 
         kernel_tq = kops.outlier_iteration_tq(p_pad, bsz, matmul_dtype)
+        if kernel_tq is None:  # reported, never silent
+            warnings.warn(
+                f"outlier QuantEase ({q}×{p_pad}) on the XLA schedule: the "
+                f"fused kernel does not fit VMEM at bsz={bsz}, {matmul_dtype}"
+            )
     use_pallas = kernel_tq is not None
     # The kernel tiles q: pad the resident state's q axis once, outside the
     # scan (the XLA path needs no q padding).

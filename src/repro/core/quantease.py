@@ -48,6 +48,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import warnings
 from typing import Optional
 
 import jax
@@ -59,6 +60,7 @@ from repro.quant.grid import Grid
 
 __all__ = [
     "QuantEaseConfig",
+    "fused_engine",
     "quantease_quantize",
     "quantease_reference",
     "layer_objective",
@@ -110,6 +112,29 @@ def _resolve_use_kernel(use_kernel: str) -> str:
     if use_kernel not in ("pallas", "pallas_hw", "xla"):
         raise ValueError(f"unknown use_kernel {use_kernel!r}")
     return use_kernel
+
+
+def fused_engine(
+    p: int, block_size: int, matmul_dtype: str, use_kernel: str = "auto"
+) -> tuple[str, str]:
+    """Which engine the fused schedule runs a layer of ``p`` input columns
+    on, and why: ``("kernel", ...)`` for the single-launch Pallas iteration,
+    ``("xla", ...)`` for the XLA schedule (same iterates)."""
+    use_kernel = _resolve_use_kernel(use_kernel)
+    if use_kernel == "xla":
+        return "xla", "XLA engine selected (off-chip, or use_kernel='xla')"
+    from repro.kernels import ops as kops
+
+    bsz = min(block_size, p)
+    p_pad = -(-p // bsz) * bsz
+    tq = kops.fused_iteration_tq(p_pad, bsz, matmul_dtype)
+    if tq is None:
+        return "xla", (
+            f"fused kernel does not fit VMEM at p_pad={p_pad}, bsz={bsz}, "
+            f"{matmul_dtype} (needs {kops.fused_iteration_bytes(p_pad, bsz, matmul_dtype, 128)} B "
+            f"at tq=128)"
+        )
+    return "kernel", f"fits VMEM at tq={tq}"
 
 
 def layer_objective(w: jax.Array, w_hat: jax.Array, sigma: jax.Array) -> jax.Array:
@@ -260,6 +285,20 @@ def _block_sweep(beta0, sig_blk, w_old_blk, scale_blk, zero_blk, n_levels, quant
     )
 
 
+def fp32_matmuls(fn):
+    """Trace ``fn`` with fp32 contract precision for its matmuls: a TPU's
+    default rounds fp32 operands to bf16, which moves CD codes off the
+    nearest grid level.  bf16 ``matmul_dtype`` operands are cast
+    explicitly."""
+
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return fn(*args, **kwargs)
+
+    return traced
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -267,6 +306,7 @@ def _block_sweep(beta0, sig_blk, w_old_blk, scale_blk, zero_blk, n_levels, quant
         "use_kernel", "matmul_dtype", "track_objective", "engine",
     ),
 )
+@fp32_matmuls
 def quantease_quantize(
     w: jax.Array,
     sigma: jax.Array,
@@ -404,16 +444,14 @@ def _quantease_2d(
         )
         w_hat, objs = _drive(step, w_hat, w32, sigma_d, pad, quant_flags, track_objective)
     elif engine == "fused":
-        kernel_fits = True
-        if use_kernel != "xla":
-            from repro.kernels import ops as kops
-
-            kernel_fits = kops.fused_iteration_tq(p_pad, bsz, matmul_dtype) is not None
-        if use_kernel == "xla" or not kernel_fits:
+        on_kernel, why = fused_engine(p, block_size, matmul_dtype, use_kernel)
+        if on_kernel == "xla":
             # XLA schedule — also the fallback when the single-kernel
             # iteration's VMEM-resident slabs (Δ accumulator + Σ̃ᵀ rows)
             # can't fit for very wide layers.  Same update order, same
-            # iterates.
+            # iterates.  A gate miss is reported, never silent.
+            if use_kernel != "xla":
+                warnings.warn(f"QuantEase ({q}×{p}) on the XLA schedule: {why}")
             step = _fused_xla_iteration_step(
                 sig_tilde, scale_pc, zero_pc, n_levels, bsz, n_blocks, cdt
             )

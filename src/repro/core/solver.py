@@ -345,7 +345,6 @@ def _shard_rows(solve: Callable, w3: jax.Array, sig3: jax.Array, grid3, mesh):
     n = mesh.shape[axis]
     if n <= 1:
         return solve(w3, sig3, grid3)
-    from jax.experimental.shard_map import shard_map
 
     G, q, p = w3.shape
     pad = (-q) % n
@@ -359,7 +358,7 @@ def _shard_rows(solve: Callable, w3: jax.Array, sig3: jax.Array, grid3, mesh):
             zero=jnp.pad(grid3.zero, ((0, 0), (0, pad), (0, 0))),
         )
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         solve,
         mesh=mesh,
         in_specs=(
@@ -368,7 +367,7 @@ def _shard_rows(solve: Callable, w3: jax.Array, sig3: jax.Array, grid3, mesh):
             PartitionSpec(None, axis, None),
         ),
         out_specs=PartitionSpec(None, axis, None),
-        check_rep=False,
+        check_vma=False,
     )
     return sharded(w3, sig3, grid3)[:, :q]
 

@@ -116,4 +116,7 @@ def elastic_mesh(model_axis: int = 1, devices=None):
         )
     data = len(devs) // model_axis
     keep = devs[: data * model_axis]
-    return jax.make_mesh((data, model_axis), ("data", "model"), devices=keep)
+    return jax.make_mesh(
+        (data, model_axis), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2, devices=keep,
+    )

@@ -35,7 +35,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import VMEM_LIMIT_BYTES
 
 __all__ = ["dequant_matmul_pallas", "select_tile_k"]
 
@@ -58,39 +62,47 @@ def select_tile_k(p: int, group_size=None, tk: int = 512):
 def _dequant_matmul_kernel(
     x_ref,  # (TM, TK) activations
     codes_ref,  # (TQ, TK) uint8 (or (TQ, TK//2) packed4)
-    scale_ref,  # (TQ, groups_per_tile) f32
-    zero_ref,  # (TQ, groups_per_tile) f32
-    o_ref,  # (TM, TQ) f32 accumulator
-    *,
-    n_k: int,
+    gid_ref,  # (1, TK) int32 — group of each tile column, within the tile
+    scale_ref,  # (1, TQ, groups_per_tile) f32
+    zero_ref,  # (1, TQ, groups_per_tile) f32
+    *rest,  # [perm_ref (TK, TK) bf16,] o_ref (TM, TQ) f32 accumulator
     packed4: bool,
-    tile_native: bool,
-    expand: int,
+    interleave: bool,
 ):
+    perm_ref, o_ref = rest if interleave else (None, rest[0])
+
     @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    codes = codes_ref[...]
+    # Mosaic has no uint8 → f32 cast; widen through int32.
+    codes = codes_ref[...].astype(jnp.int32)
     if packed4:
-        lo = codes & 0xF
-        hi = codes >> 4
-        if tile_native:
-            # Prepacked plane-wise tile (pack.prepack_codes): lo nibbles are
-            # the tile's first TK/2 columns, hi nibbles the rest — natural
-            # column order falls out of a concat, no lane interleave.
-            codes = jnp.concatenate([lo, hi], axis=-1)
-        else:
-            # Linear layout: packed byte b holds codes (2b, 2b+1) —
-            # interleave back to (TQ, TK).
-            codes = jnp.stack([lo, hi], axis=-1).reshape(codes.shape[0], -1)
-    scale = scale_ref[...]
-    zero = zero_ref[...]
-    if expand > 1:
-        # One (s, z) pair per contiguous group of `expand` columns.
-        scale = jnp.repeat(scale, expand, axis=1)
-        zero = jnp.repeat(zero, expand, axis=1)
-    w = (codes.astype(jnp.float32) - zero) * scale  # (TQ, TK)
+        # Lo nibbles, then hi nibbles.  The tile-native prepack
+        # (pack.prepack_codes) stores a tile's first TK/2 columns in the lo
+        # nibbles, so this concat is already natural column order.
+        codes = jnp.concatenate([codes & 0xF, codes >> 4], axis=-1)
+        if interleave:
+            # Linear layout: byte b holds columns (2b, 2b+1), so the concat
+            # is [even | odd].  Mosaic cannot shuffle lanes, so a 0/1
+            # permutation on the MXU restores natural order — exact, since
+            # codes ≤ 15 are exact in bf16 and each output sums one term.
+            codes = jnp.dot(
+                codes.astype(jnp.bfloat16), perm_ref[...],
+                preferred_element_type=jnp.float32,
+            )
+    codes = codes.astype(jnp.float32)
+    scale = scale_ref[0]
+    zero = zero_ref[0]
+    n_g = scale.shape[1]
+    if n_g > 1:  # k-tile covers n_g whole groups: expand by selects (exact)
+        gid = gid_ref[...]
+        s_full, z_full = scale[:, :1], zero[:, :1]
+        for g in range(1, n_g):
+            s_full = jnp.where(gid == g, scale[:, g : g + 1], s_full)
+            z_full = jnp.where(gid == g, zero[:, g : g + 1], z_full)
+        scale, zero = s_full, z_full
+    w = (codes - zero) * scale  # (TQ, TK)
     x = x_ref[...].astype(jnp.float32)
     o_ref[...] += jnp.dot(x, w.T, preferred_element_type=jnp.float32)
 
@@ -167,18 +179,33 @@ def dequant_matmul_pallas(
     n_k = pp // tk
     ck = tk // 2 if packed4 else tk  # codes tile width in stored bytes
 
-    if tk % gsz == 0:  # k-tile covers whole groups → (TQ, tk/gsz) slab per tile
+    col = np.arange(tk)
+    gid = jnp.asarray((col // gsz if tk % gsz == 0 else 0 * col)[None], jnp.int32)
+    interleave = packed4 and not tile_native
+    extra_args, extra_specs = [], []
+    if interleave:  # perm[c, n]: concat column c ([even | odd]) → column n
+        src = np.concatenate([col[0::2], col[1::2]])
+        perm = np.zeros((tk, tk), np.float32)
+        perm[np.arange(tk), src] = 1.0
+        extra_args = [jnp.asarray(perm, jnp.bfloat16)]
+        extra_specs = [pl.BlockSpec((tk, tk), lambda i, j, k: (0, 0))]
+
+    # Scale/zero ride as (n_slabs, qp, groups_per_tile) so each k-step's
+    # block is (1, TQ, groups_per_tile): its last dim is the array's whole
+    # last dim, which the TPU (8, 128) tiling rule accepts at any width.
+    if tk % gsz == 0:  # k-tile covers whole groups → one slab per k-tile
         g_tile = tk // gsz
-        scale_spec = pl.BlockSpec((tq, g_tile), lambda i, j, k: (j, k))
-        expand = gsz
-    else:  # k-tile inside one group (gsz % tk == 0, and per-channel where
-        # gsz = p): a (TQ, 1) slab addressed by the k-tile's group index.
-        scale_spec = pl.BlockSpec((tq, 1), lambda i, j, k: (j, (k * tk) // gsz))
-        expand = tk
+        slabs = lambda a: a.reshape(qp, n_k, g_tile).transpose(1, 0, 2)
+        scale_spec = pl.BlockSpec((1, tq, g_tile), lambda i, j, k: (k, j, 0))
+    else:  # k-tile inside one group (gsz % tk == 0): slab = the k-tile's group
+        slabs = lambda a: a.T[:, :, None]
+        scale_spec = pl.BlockSpec(
+            (1, tq, 1), lambda i, j, k: ((k * tk) // gsz, j, 0)
+        )
+    scale, zero = slabs(scale), slabs(zero)
 
     kernel = functools.partial(
-        _dequant_matmul_kernel, n_k=n_k, packed4=packed4,
-        tile_native=tile_native, expand=expand,
+        _dequant_matmul_kernel, packed4=packed4, interleave=interleave
     )
     out = pl.pallas_call(
         kernel,
@@ -186,16 +213,17 @@ def dequant_matmul_pallas(
         in_specs=[
             pl.BlockSpec((tm, tk), lambda i, j, k: (i, k)),
             pl.BlockSpec((tq, ck), lambda i, j, k: (j, k)),
+            pl.BlockSpec((1, tk), lambda i, j, k: (0, 0)),
             scale_spec,
             scale_spec,
+            *extra_specs,
         ],
         out_specs=pl.BlockSpec((tm, tq), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, qp), jnp.float32),
         interpret=interpret,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel", "arbitrary"))
-        )
-        if not interpret
-        else None,
-    )(x, codes, scale, zero)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        ),
+    )(x, codes, gid, scale, zero, *extra_args)
     return out[:m, :q].astype(out_dtype)

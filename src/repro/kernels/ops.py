@@ -1,20 +1,26 @@
 """Jit'd dispatch wrappers for the Pallas kernels.
 
 Callers (repro.core.quantease, repro.serve) use these entry points; the
-``interpret`` flag routes to Pallas interpret-mode on CPU (this container)
-and compiled Mosaic on real TPUs.  ``ref.py`` holds the oracles; the
-dispatchers never change semantics, only execution engines.
+``interpret`` flag routes to Pallas interpret-mode on CPU and compiled
+Mosaic on a TPU, where interpret mode is never taken.  ``ref.py`` holds the
+oracles; the dispatchers never change semantics, only execution engines.
+
+On a TPU, every time a serving dispatcher gives way to its XLA reference
+(fit gate, ragged layout, injected fault) it is counted in
+:data:`fallbacks` — a chip run checks the count is zero, so a kernel that
+silently stops being used cannot pass for one that runs.
 """
 
 from __future__ import annotations
 
+import collections
 import functools
 
 import jax
 import jax.numpy as jnp
 
 from repro.faults import fault_point
-from repro.kernels import ref
+from repro.kernels import VMEM_LIMIT_BYTES, ref
 from repro.kernels.dequant_matmul import dequant_matmul_pallas
 from repro.kernels.paged_attention import paged_attention_pallas
 from repro.kernels.quantease_cd import (
@@ -41,13 +47,29 @@ __all__ = [
     "paged_attention",
     "paged_attention_fits_vmem",
     "on_tpu",
+    "fallbacks",
 ]
 
-_VMEM_BUDGET = 12 * 1024 * 1024  # of ~16 MB VMEM, leaving double-buffer headroom
+# Budget the fit gates hold each kernel to: the scoped limit asked of Mosaic
+# less headroom for its internal scratch.  The byte formulas count every
+# pipelined block twice (double buffering); checked against the TPU
+# compiler at phi3 widths by tests/test_tpu_compile.py.
+_VMEM_BUDGET = VMEM_LIMIT_BYTES - 16 * 1024 * 1024
+
+# (kernel, reason) → times a serving dispatcher on a TPU took the XLA
+# reference instead of its kernel (counted at trace time).
+fallbacks: collections.Counter = collections.Counter()
 
 
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def _interpret(interpret) -> bool:
+    """Interpret mode only off the chip: on a TPU the compiled kernel runs."""
+    if on_tpu():
+        return False
+    return True if interpret is None else interpret
 
 
 def block_sweep_bytes(bsz: int, tq: int) -> int:
@@ -78,8 +100,7 @@ def quantease_block_sweep(
     independent layers at once — pallas_call's batching rule folds the vmap
     into an extra grid dimension, so the grouped-block solver issues a
     single kernel launch per column block."""
-    if interpret is None:
-        interpret = not on_tpu()
+    interpret = _interpret(interpret)
     q, bsz = beta0.shape[-2], beta0.shape[-1]
     tq = block_sweep_tq(q, bsz)
     if tq is None:
@@ -104,11 +125,15 @@ def quantease_block_sweep(
 def fused_iteration_bytes(
     p_pad: int, bsz: int, matmul_dtype: str, tq: int
 ) -> int:
-    """VMEM working set of one fused-iteration program at tile ``tq``: the
-    (p_pad × tq) fp32 Δ accumulator scratch, the (bsz × p_pad) Σ̃ᵀ
-    correction slab (bf16 halves it), and ~7 (bsz × tq) fp32 tiles."""
-    sig_bytes = bsz * p_pad * (2 if matmul_dtype == "bfloat16" else 4)
-    return p_pad * tq * 4 + sig_bytes + 7 * bsz * tq * 4
+    """VMEM working set of one fused-iteration program at tile ``tq``.
+
+    Double-buffered blocks: the resident (p_pad × tq) fp32 Δ_prev, the
+    (bsz × p_pad) Σ̃ᵀ correction slab (bf16 halves it), the (bsz × bsz)
+    diagonal block and 7 (bsz × tq) fp32 tiles.  Once: the (p_pad × tq)
+    fp32 Δ scratch, plus its bf16 copy for a bf16 correction matmul."""
+    cd = 2 if matmul_dtype == "bfloat16" else 4
+    blocks = p_pad * tq * 4 + bsz * p_pad * cd + bsz * bsz * 4 + 7 * bsz * tq * 4
+    return 2 * blocks + p_pad * tq * 4 + (p_pad * tq * cd if cd == 2 else 0)
 
 
 def fused_iteration_tq(p_pad: int, bsz: int, matmul_dtype: str = "float32", tq: int = 256):
@@ -150,8 +175,7 @@ def quantease_fused_iteration(
     :func:`fused_iteration_tq`'s VMEM-fitted choice; callers should gate on
     that helper returning non-None before taking this path.
     """
-    if interpret is None:
-        interpret = not on_tpu()
+    interpret = _interpret(interpret)
     p_pad = sig_tilde.shape[-1]
     if tq is None:
         tq = fused_iteration_tq(p_pad, bsz, matmul_dtype)
@@ -185,11 +209,15 @@ def outlier_iteration_bytes(
     p_pad: int, bsz: int, matmul_dtype: str, tq: int
 ) -> int:
     """VMEM working set of one outlier-iteration program: beyond the base
-    kernel's set, a second (p_pad × tq) fp32 slab (the R accumulator
-    output) and a second (p_pad × bsz) Σ̃ slab (the suffix column block;
-    bf16 halves both Σ̃ slabs)."""
-    sig_bytes = 2 * bsz * p_pad * (2 if matmul_dtype == "bfloat16" else 4)
-    return 2 * p_pad * tq * 4 + sig_bytes + 8 * bsz * tq * 4
+    kernel's set, a second double-buffered (p_pad × tq) fp32 slab (the R
+    accumulator output), a second Σ̃ slab (the suffix column block; bf16
+    halves both), and the (p_pad × tq) fp32 suffix-residual temporary."""
+    cd = 2 if matmul_dtype == "bfloat16" else 4
+    blocks = (
+        2 * p_pad * tq * 4 + 2 * bsz * p_pad * cd + bsz * bsz * 4
+        + 8 * bsz * tq * 4
+    )
+    return 2 * blocks + 2 * p_pad * tq * 4 + (p_pad * tq * cd if cd == 2 else 0)
 
 
 def outlier_iteration_tq(
@@ -233,8 +261,7 @@ def quantease_outlier_iteration(
     ``(w_new, base_new, delta_pure, r)`` — see
     :func:`repro.kernels.quantease_cd.quantease_outlier_iteration_pallas`.
     """
-    if interpret is None:
-        interpret = not on_tpu()
+    interpret = _interpret(interpret)
     p_pad = sig_tilde.shape[-1]
     if tq is None:
         tq = outlier_iteration_tq(p_pad, bsz, matmul_dtype)
@@ -287,8 +314,7 @@ def quantease_outlier_iteration_t(
     operands (``sig_corr``/``sig_t``/``scale_t``/``zero_t``) are prepped
     once by the caller.  Returns ``(w_new_t, base_new_t, delta_pure_t,
     r_t)``, all (p_pad, qp)."""
-    if interpret is None:
-        interpret = not on_tpu()
+    interpret = _interpret(interpret)
     p_pad = base_t.shape[-2]
     if outlier_iteration_bytes(p_pad, bsz, matmul_dtype, tq) > _VMEM_BUDGET:
         raise ValueError(
@@ -324,16 +350,15 @@ def paged_attention_fits_vmem(
     pages are quantized, and the fixed per-sequence set (query tile, fp32
     softmax accumulators, output tile).  ``kv_bytes`` is per *element*:
     2 for bf16, 1 for int8, 0.5 for packed int4 (two codes per stored
-    byte).  Same 12 MB budget/headroom policy as
-    :func:`fused_iteration_tq`; a non-fit must take the XLA gather
-    fallback — there is no smaller tile to retry, pages are the tile.
+    byte).  Same budget as :func:`fused_iteration_tq`; a non-fit must take
+    the XLA gather fallback — there is no smaller tile to retry, pages are
+    the tile.
     """
     pages = int(2 * 2 * page_size * kvp * hd * kv_bytes)  # k+v, double-buffered
     if quantized:
         pages += 2 * 2 * page_size * kvp * 4
     fixed = kvp * g * hd * 4 * 3 + kvp * g * 4 * 2  # q + acc + out, m + l
-    budget = 12 * 1024 * 1024
-    return pages + fixed <= budget
+    return pages + fixed <= _VMEM_BUDGET
 
 
 def paged_attention(
@@ -368,7 +393,9 @@ def paged_attention(
             "int4-packed KV pages require scale planes (dequant-in-kernel)"
         )
 
-    def reference():
+    def reference(reason):
+        if on_tpu():
+            fallbacks[("paged_attention", reason)] += 1
         return ref.paged_attention_ref(
             q, k_pages, v_pages, page_table, lengths,
             window=window, attn_softcap=attn_softcap,
@@ -380,11 +407,10 @@ def paged_attention(
     # XLA gather reference, which reads the same pages bitwise (tested), so
     # outputs are unchanged.  Fires at dispatch time (trace time under jit).
     if fault_point("kernel.dispatch") == "deny":
-        return reference()
-    if interpret is None:
-        if not on_tpu():
-            return reference()
-        interpret = False
+        return reference("fault")
+    if interpret is None and not on_tpu():
+        return reference("off-chip")
+    interpret = _interpret(interpret)
     psz = k_pages.shape[1]
     _, kvp, g, hd = q.shape
     if not paged_attention_fits_vmem(
@@ -392,7 +418,7 @@ def paged_attention(
         kv_bytes=0.5 if kv_packed4 else k_pages.dtype.itemsize,
         quantized=quantized,
     ):
-        return reference()
+        return reference("vmem")
     return paged_attention_pallas(
         q, k_pages, v_pages, page_table, lengths,
         window=window, attn_softcap=attn_softcap,
@@ -470,7 +496,9 @@ def dequant_matmul(
     uniform = n_groups == 1 or (p % gsz == 0 and p // gsz == n_groups)
     tiled = packed4 and pack_layout == "tile"
 
-    def reference():
+    def reference(reason):
+        if on_tpu():
+            fallbacks[("dequant_matmul", reason)] += 1
         return ref.dequant_matmul_ref(
             x, _unpacked(codes, packed4, pack_layout, pack_tile), scale, zero,
             out_dtype=out_dtype, group_size=group_size,
@@ -479,21 +507,20 @@ def dequant_matmul(
     # Injection point "kernel.dispatch": "deny" degrades to the XLA
     # reference (same semantics; see paged_attention's note).
     if fault_point("kernel.dispatch") == "deny":
-        return reference()
-    if interpret is None:
-        if not on_tpu():
-            return reference()
-        interpret = False
+        return reference("fault")
+    if interpret is None and not on_tpu():
+        return reference("off-chip")
+    interpret = _interpret(interpret)
     if not dequant_matmul_fits_vmem(x.shape[0], codes.shape[0], p):
-        return reference()
+        return reference("vmem")
     kw = dict(packed4=packed4, out_dtype=out_dtype, interpret=interpret)
     if tiled:
         if p % pack_tile:  # prepack left the ragged tail linear — ref only
-            return reference()
+            return reference("ragged-tile")
         kw.update(pack_layout="tile", tk=pack_tile)
     if n_groups > 1:
         if not uniform:  # ragged last group — reference path only
-            return reference()
+            return reference("ragged-groups")
         return dequant_matmul_pallas(x, codes, scale, zero, **kw)
     s = scale.reshape(-1)
     z = zero.reshape(-1)

@@ -48,12 +48,29 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import VMEM_LIMIT_BYTES
+
 __all__ = [
     "quantease_block_sweep_pallas",
     "quantease_fused_iteration_pallas",
     "quantease_outlier_iteration_pallas",
     "quantease_outlier_iteration_t_pallas",
 ]
+
+
+# fp32 operands stay fp32 on the MXU.  Mosaic's default contract precision
+# rounds them to bf16 (2.4e-3 relative error on a v5e): at phi3 widths that
+# moved 4.7% of one quantized iteration's codes away from the fp32
+# schedule's (a wrong rounding early in a row cascades along the row).
+_FP32 = jax.lax.Precision.HIGHEST
+
+
+def _precision(dtype):
+    """fp32 contract precision for fp32 operands; bf16 operands (the bf16
+    correction option) take Mosaic's default, which refuses fp32 for them.
+    Explicit either way, so a caller's default_matmul_precision never
+    reaches the kernel."""
+    return _FP32 if dtype == jnp.float32 else jax.lax.Precision.DEFAULT
 
 
 def _sweep_kernel(
@@ -75,7 +92,8 @@ def _sweep_kernel(
         # corr = Σ̃[:, i] · Δ  — rows ≥ i of Δ are still zero, so no mask.
         sig_row = sig_t_ref[pl.ds(i, 1), :]  # (1, B)
         corr = jnp.dot(
-            sig_row, delta_t_ref[...], preferred_element_type=jnp.float32
+            sig_row, delta_t_ref[...], preferred_element_type=jnp.float32,
+            precision=_FP32,
         )  # (1, TQ)
         beta = beta0_t_ref[pl.ds(i, 1), :] + corr
         if quantize:
@@ -146,6 +164,7 @@ def quantease_block_sweep_pallas(
             jax.ShapeDtypeStruct((bsz, qp), jnp.float32),
         ],
         interpret=interpret,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
     )(beta0_t, sig_t, w_old_t, scale_t, zero_t)
     return w_new_t.T[:q], delta_t.T[:q]
 
@@ -186,6 +205,7 @@ def _fused_iter_kernel(
         sig_corr_ref[...],
         delta_acc[...].astype(corr_dtype),
         preferred_element_type=jnp.float32,
+        precision=_precision(corr_dtype),
     )  # (B, TQ)
     beta0 = base_t_ref[...] + corr
     base_out_t_ref[...] = beta0
@@ -196,9 +216,10 @@ def _fused_iter_kernel(
     def body(i, _):
         sig_row = sig_diag_ref[pl.ds(i, 1), :]  # (1, B)
         c = jnp.dot(
-            sig_row, delta_out_t_ref[...], preferred_element_type=jnp.float32
+            sig_row, delta_out_t_ref[...], preferred_element_type=jnp.float32,
+            precision=_FP32,
         )  # (1, TQ)
-        beta = jax.lax.dynamic_slice(beta0, (i, 0), (1, beta0.shape[1])) + c
+        beta = base_out_t_ref[pl.ds(i, 1), :] + c  # β₀ row i (stored above)
         if quantize:
             sc = scale_t_ref[pl.ds(i, 1), :]
             zc = zero_t_ref[pl.ds(i, 1), :]
@@ -290,11 +311,10 @@ def quantease_fused_iteration_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((p_pad, tq), jnp.float32)],
         interpret=interpret,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "arbitrary"))
-        )
-        if not interpret
-        else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        ),
     )(base_t, sig_corr, sig_t, w_old_t, scale_t, zero_t, delta_prev_t)
     return w_new_t.T[:q], base_out_t.T[:q], delta_out_t.T[:q]
 
@@ -343,6 +363,7 @@ def _outlier_iter_kernel(
         sig_corr_ref[...],
         delta_acc[...].astype(corr_dtype),
         preferred_element_type=jnp.float32,
+        precision=_precision(corr_dtype),
     )  # (B, TQ)
     beta0 = base_t_ref[...] - dh_prev_t_ref[...] + corr
     base_out_t_ref[...] = beta0
@@ -354,9 +375,10 @@ def _outlier_iter_kernel(
     def body(i, _):
         sig_row = sig_diag_ref[pl.ds(i, 1), :]  # (1, B)
         c = jnp.dot(
-            sig_row, dpure_t_ref[...], preferred_element_type=jnp.float32
+            sig_row, dpure_t_ref[...], preferred_element_type=jnp.float32,
+            precision=_FP32,
         )  # (1, TQ) — rows ≥ i still zero; dĤ_prev cancels in the difference
-        beta = jax.lax.dynamic_slice(beta0, (i, 0), (1, beta0.shape[1])) + c
+        beta = base_out_t_ref[pl.ds(i, 1), :] + c  # β₀ row i (stored above)
         if quantize:
             sc = scale_t_ref[pl.ds(i, 1), :]
             zc = zero_t_ref[pl.ds(i, 1), :]
@@ -381,6 +403,7 @@ def _outlier_iter_kernel(
         sig_col_ref[...],
         dpure_t_ref[...].astype(corr_dtype),
         preferred_element_type=jnp.float32,
+        precision=_precision(corr_dtype),
     )  # (p_pad, TQ)
     row = jax.lax.broadcasted_iota(jnp.int32, contrib.shape, 0)
     r_t_ref[...] += jnp.where(row < (b + 1) * bsz, contrib, 0.0)
@@ -513,10 +536,9 @@ def quantease_outlier_iteration_t_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((p_pad, tq), jnp.float32)],
         interpret=interpret,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "arbitrary"))
-        )
-        if not interpret
-        else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        ),
     )(base_t, sig_corr, sig_corr, sig_t, w_old_t, scale_t, zero_t,
       dh_prev_t, delta_prev_t)

@@ -100,6 +100,10 @@ def main():
     ap.add_argument("--out", default="benchmarks/dryrun_results")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from repro.configs.base import ARCH_IDS
     from repro.launch.specs import CELLS
 

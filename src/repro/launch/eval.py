@@ -60,6 +60,10 @@ def main():
                     help="seconds-scale budgets, 2-cell grid (schema unchanged)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     import os
 
     import jax

@@ -92,6 +92,10 @@ def main():
                          "inline JSON string (see repro.faults.FaultPlan)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from repro.faults import FaultPlan, fault_plan
 
     plan_obj = FaultPlan.from_spec(args.fault_plan) if args.fault_plan else None
@@ -142,11 +146,19 @@ def _run(args):
     if os.path.exists(progress_path):
         os.remove(progress_path)
 
-    like_params = jax.tree.map(
-        lambda s: jnp.zeros(s.shape, s.dtype), param_shapes(plan)
-    )
-    like = {"params": like_params, "opt": adamw_init(like_params, AdamWConfig())}
-    state, manifest, skipped = ckpt.load_last_good(args.ckpt_dir, like)
+    # Shapes, not arrays, as the template: the loader only reads shapes and
+    # dtypes, and a zero-filled copy of a full-width model would not fit
+    # beside the loaded one on the device.
+    shapes = param_shapes(plan)
+    try:  # quantized/eval checkpoints hold params only …
+        state, manifest, skipped = ckpt.load_last_good(
+            args.ckpt_dir, {"params": shapes}
+        )
+    except ckpt.CheckpointCorrupt:  # … train checkpoints also carry AdamW moments
+        opt = jax.eval_shape(lambda p: adamw_init(p, AdamWConfig()), shapes)
+        state, manifest, skipped = ckpt.load_last_good(
+            args.ckpt_dir, {"params": shapes, "opt": opt}
+        )
     for step, reason in skipped:
         print(f"WARNING: skipped damaged checkpoint step_{step}: "
               f"{reason.splitlines()[0]}", file=sys.stderr)

@@ -105,6 +105,10 @@ def main():
                          "(same arch)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from repro.faults import FaultPlan, fault_plan
 
     plan_obj = FaultPlan.from_spec(args.fault_plan) if args.fault_plan else None
@@ -117,7 +121,6 @@ def main():
 
 def _run(args):
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
     from repro.configs import get_config
@@ -130,9 +133,7 @@ def _run(args):
     if args.reduce:
         cfg = reduced(cfg)
     plan = make_plan(cfg, 1, kv_cache_dtype=args.kv_dtype)
-    like_params = jax.tree.map(
-        lambda s: jnp.zeros(s.shape, s.dtype), param_shapes(plan)
-    )
+    like_params = param_shapes(plan)  # shapes only: no full-width zero tree
 
     def load_params(ckpt_dir):
         try:  # quantized/eval checkpoints hold params only …
@@ -145,7 +146,9 @@ def _run(args):
             state, manifest = ckpt.load_checkpoint(
                 ckpt_dir,
                 {"params": like_params,
-                 "opt": adamw_init(like_params, AdamWConfig())},
+                 "opt": jax.eval_shape(
+                     lambda p: adamw_init(p, AdamWConfig()), like_params
+                 )},
             )
         return state["params"], manifest
 

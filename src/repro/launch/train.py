@@ -48,6 +48,10 @@ def main():
     ap.add_argument("--microbatches", type=int, default=1)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from repro.configs import get_config
     from repro.train.optimizer import AdamWConfig
     from repro.train.trainer import Trainer, TrainerConfig

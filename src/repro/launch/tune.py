@@ -77,6 +77,10 @@ def main():
                          "inline JSON string (see repro.faults.FaultPlan)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from repro.faults import FaultPlan, fault_plan
 
     plan_obj = FaultPlan.from_spec(args.fault_plan) if args.fault_plan else None

@@ -14,7 +14,8 @@ all-reduce / reduce-scatter / all-to-all / collective-permute, converting
 to per-device *link* bytes with ring-algorithm factors over the size of the
 participating group.
 
-Hardware model (TPU v5e): 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+Hardware model: per-chip peaks keyed by ``device_kind`` (:data:`HW_BY_KIND`);
+a kind missing from the table is an error, never a silent default.
 """
 
 from __future__ import annotations
@@ -25,7 +26,7 @@ import re
 from typing import Optional
 
 __all__ = [
-    "HW", "RooflineReport", "analyze_compiled", "collective_bytes",
+    "HW", "HW_BY_KIND", "TARGET_KIND", "hw_for", "RooflineReport", "analyze_compiled", "collective_bytes",
     "WeightLayoutDecision", "choose_weight_layout", "weight_bytes",
     "paged_kv_bytes_per_token",
 ]
@@ -33,9 +34,32 @@ __all__ = [
 
 @dataclasses.dataclass(frozen=True)
 class HW:
-    peak_flops: float = 197e12  # bf16
-    hbm_bw: float = 819e9
-    ici_bw: float = 50e9  # per link (one direction)
+    peak_flops: float  # bf16
+    hbm_bw: float
+    ici_bw: float  # per link (one direction)
+
+
+# Published per-chip peaks, keyed by jax's ``device.device_kind``.
+# TPU v5e: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB
+# HBM at 819 GB/s, 1,600 Gbit/s of ICI over four links).
+HW_BY_KIND = {
+    "TPU v5 lite": HW(peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+# The chip this repo plans for where no device is attached (dry runs of the
+# production mesh, serving-layout decisions made off the chip).
+TARGET_KIND = "TPU v5 lite"
+
+
+def hw_for(device_kind: str) -> HW:
+    """Peaks of one chip of ``device_kind``; unknown kinds are an error."""
+    try:
+        return HW_BY_KIND[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device_kind {device_kind!r}; add them "
+            "to roofline.analysis.HW_BY_KIND with their source"
+        ) from None
 
 
 # s4/u4 are *packed* two-per-byte in HBM (quant/pack.py, the paged int4 KV
@@ -138,10 +162,11 @@ def collective_bytes(hlo_text: str, n_devices: int) -> dict:
 # modelling the *effective* bandwidth of each unpack pattern.
 #
 #   linear-unpacked : 1 B/elem, contiguous reads            (any bits)
-#   linear-packed   : 0.5 B/elem, in-kernel nibble interleave — the
-#                     stack([lo, hi]).reshape shuffle reads contiguous words
-#                     but scatters them across lanes; modelled as a gather
-#                     at `_INTERLEAVE_DERATE` of peak HBM bw (bits == 4)
+#   linear-packed   : 0.5 B/elem, in-kernel nibble interleave — Mosaic
+#                     cannot shuffle lanes, so the kernel restores column
+#                     order with a (tk × tk) 0/1 permutation on the MXU;
+#                     modelled as `_INTERLEAVE_DERATE` of peak HBM bw
+#                     (bits == 4)
 #   tile-native     : 0.5 B/elem, codes pre-reordered so each k-tile's low
 #                     nibbles are its first tk/2 columns and the high
 #                     nibbles the rest — unpack is two shifts + a concat,
@@ -191,7 +216,7 @@ class WeightLayoutDecision:
 def choose_weight_layout(
     q: int, p: int, *, bits: int, group_size: Optional[int] = None,
     tile_k: Optional[int] = None, backend: str = "tpu", m: int = 1,
-    hw: HW = HW(),
+    hw: HW = HW_BY_KIND[TARGET_KIND],
 ) -> WeightLayoutDecision:
     """Pick the serving storage layout for one (q, p) quantized linear.
 
@@ -257,7 +282,7 @@ def analyze_compiled(
     compiled,
     n_devices: int,
     model_flops: float,
-    hw: HW = HW(),
+    hw: HW = HW_BY_KIND[TARGET_KIND],
 ) -> RooflineReport:
     from repro.roofline.hlo_cost import analyze_hlo
 

@@ -354,10 +354,16 @@ def prepack_params_for_serving(plan: M.ModelPlan, params, *, backend=None):
     banner).
     """
     from repro.kernels.dequant_matmul import select_tile_k
-    from repro.roofline.analysis import choose_weight_layout
+    from repro.roofline.analysis import choose_weight_layout, hw_for
 
     if backend is None:
         backend = jax.default_backend()
+    # On a chip, its own peaks (an unknown kind is an error); off the chip,
+    # the roofline's planning target.
+    hw_kw = (
+        {"hw": hw_for(jax.devices()[0].device_kind)}
+        if jax.default_backend() == "tpu" else {}
+    )
     decisions: dict[str, str] = {}
 
     def prepack_leaf(path: str, leaf):
@@ -368,7 +374,8 @@ def prepack_params_for_serving(plan: M.ModelPlan, params, *, backend=None):
         q, p = leaf.shape[-2], leaf.shape[-1]
         tk = select_tile_k(p, leaf.group_size)
         dec = choose_weight_layout(
-            q, p, bits=4, group_size=leaf.group_size, tile_k=tk, backend=backend
+            q, p, bits=4, group_size=leaf.group_size, tile_k=tk,
+            backend=backend, **hw_kw,
         )
         if dec.kind != "tile":
             # The prepack never unpacks checkpoint codes back into HBM, so a

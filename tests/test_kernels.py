@@ -162,3 +162,24 @@ def test_dequant_matmul_tile_layout_grouped(rng):
         pack_layout="tile", pack_tile=tk, out_dtype=jnp.float32, interpret=True,
     )
     np.testing.assert_array_equal(np.asarray(y_lin), np.asarray(y_tile))
+
+
+def test_reference_fallback_is_counted_on_tpu(rng, monkeypatch):
+    """On a TPU, a serving dispatcher that takes its XLA reference instead
+    of the kernel is counted (a chip run requires zero); off the chip the
+    reference is the normal path and is not counted."""
+    m, p, q = 2, 96, 16  # 96 columns in groups of 40: a ragged last group
+    codes = jnp.asarray(rng.integers(0, 16, (q, p)).astype(np.uint8))
+    scale = jnp.asarray((rng.random((q, 3)) * 0.1 + 0.01).astype(np.float32))
+    zero = jnp.asarray(rng.integers(0, 16, (q, 3)).astype(np.float32))
+    x = jnp.asarray(rng.standard_normal((m, p)).astype(np.float32))
+    want = ref.dequant_matmul_ref(x, codes, scale, zero, group_size=40)
+    monkeypatch.setattr(ops, "fallbacks", type(ops.fallbacks)())
+    ops.dequant_matmul(x, codes, scale, zero, group_size=40, out_dtype=jnp.float32)
+    assert not ops.fallbacks
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    got = ops.dequant_matmul(
+        x, codes, scale, zero, group_size=40, out_dtype=jnp.float32
+    )
+    assert dict(ops.fallbacks) == {("dequant_matmul", "ragged-groups"): 1}
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

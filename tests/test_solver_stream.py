@@ -28,6 +28,7 @@ from repro.core.solver import (
     ptq_quantize_model,
 )
 from repro.core.quantease import quantease_quantize, relative_error
+from repro.launch.mesh import make_data_mesh
 from repro.core.gptq import gptq_quantize
 from repro.models import init_params, make_plan, train_loss
 from repro.models import model as M
@@ -228,7 +229,7 @@ def test_sharded_gram_fallback_matches_local(rng):
 
 @pytest.mark.skipif(jax.device_count() < 2, reason="needs ≥2 devices")
 def test_sharded_engine_matches_single_device():
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_data_mesh()
     plan, params, calib = _small()
     cfg = PTQConfig(method="quantease", spec=GridSpec(bits=4), iterations=4)
     _, rep_local = ptq_quantize_model(plan, params, calib, cfg)
@@ -274,3 +275,28 @@ def test_quantized_model_still_runs():
                   stream_chunk=1),
     )
     assert bool(jnp.isfinite(train_loss(plan, qp, calib[0])))
+
+
+def test_quantize_launcher_loads_params_only_checkpoint(tmp_path, monkeypatch, capsys):
+    """launch/quantize.py takes a params-only checkpoint (no AdamW moments)
+    as well as a train checkpoint; its template is shapes, not arrays."""
+    import sys
+
+    from repro.dist import checkpoint as ckpt
+    from repro.launch import compile_cache, quantize
+    from repro.launch.train import reduced
+
+    plan = make_plan(reduced(get_config("stablelm_12b")), 1)
+    ckpt.save_checkpoint(
+        str(tmp_path / "src"), 3, {"params": init_params(plan, jax.random.PRNGKey(0))}
+    )
+    monkeypatch.setattr(compile_cache, "enable_compile_cache", lambda: "")
+    monkeypatch.setattr(sys, "argv", [
+        "quantize", "--arch", "stablelm_12b", "--reduce",
+        "--ckpt-dir", str(tmp_path / "src"), "--out-dir", str(tmp_path / "out"),
+        "--method", "rtn", "--calib-batches", "1", "--seq", "16",
+    ])
+    quantize.main()
+    out = capsys.readouterr().out
+    assert "loaded checkpoint step 3" in out
+    assert ckpt.latest_step(str(tmp_path / "out")) == 3
