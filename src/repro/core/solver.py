@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec
 
+from repro import obs
 from repro.core import awq, gptq, outlier, quantease, rtn, spqr
 from repro.core.calib import CalibStats
 from repro.core.quantease import relative_error
@@ -488,25 +489,30 @@ def _quantize_block(
 
     new = dict(p_blk)
     for (shape, _), (eff, group) in groups.items():
-        w3 = jnp.concatenate([it.w3 for it in group], axis=0)
-        sig3 = jnp.concatenate([it.sig3 for it in group], axis=0)
-        w_hat3, hs, grids = _solve_group(w3, sig3, eff, mesh)
-        errs = relative_error(w3, _effective(w_hat3, hs), sig3)
-        if cfg.collect_sensitivity and sens is not None:
-            for it in group:
-                lam = jax.vmap(outlier.power_lambda_max)(it.sig3)
-                if it.moe:
-                    for e in range(it.sig3.shape[0]):
-                        sens[f"{it.key}.e{e}"] = float(lam[e])
-                else:
-                    sens[it.key] = float(lam[0])
+        G_all = sum(it.w3.shape[0] for it in group)
+        with obs.span("ptq.solve", block=scope, shape=str(tuple(shape)), G=G_all,
+                      method=eff.method):
+            w3 = jnp.concatenate([it.w3 for it in group], axis=0)
+            sig3 = jnp.concatenate([it.sig3 for it in group], axis=0)
+            w_hat3, hs, grids = _solve_group(w3, sig3, eff, mesh)
+            # One host read per group, inside the span: it waits for the solve.
+            errs = np.asarray(relative_error(w3, _effective(w_hat3, hs), sig3))
+            if cfg.collect_sensitivity and sens is not None:
+                for it in group:
+                    lam = jax.vmap(outlier.power_lambda_max)(it.sig3)
+                    if it.moe:
+                        for e in range(it.sig3.shape[0]):
+                            sens[f"{it.key}.e{e}"] = float(lam[e])
+                    else:
+                        sens[it.key] = float(lam[0])
         off = 0
         for it in group:
             G = it.w3.shape[0]
             sl = slice(off, off + G)
-            _scatter_item(
-                it, w_hat3[sl], hs[sl], errs[sl], new, eff, report, grids[sl]
-            )
+            with obs.span("ptq.emit", block=scope, linear=it.name):
+                _scatter_item(
+                    it, w_hat3[sl], hs[sl], errs[sl], new, eff, report, grids[sl]
+                )
             off += G
     return new
 
@@ -608,6 +614,17 @@ def ptq_quantize_model(
     receives one dict per quantized block — the launcher renders these as
     progress lines and a block-level progress file (an audit trail for
     post-hoc/restart inspection; quantization itself restarts from scratch).
+    Its ``seconds`` is host time from the block's start up to the dispatch of
+    its recompute: it includes the waits on the solves, not the recompute's
+    device time, so it is not the device's time for the block.  Inside
+    ``obs.record()`` the dict also holds ``phase_s`` (host seconds in each
+    ``ptq.*`` span of the block) and the block's ``compiles``/``compile_s``
+    (``cache_loads`` of them read from the persistent compilation cache).
+
+    Phase spans (``repro.obs``), each with ``block=<scope>``: ``ptq.capture``
+    once a block, ``ptq.solve`` once per same-shape group (with ``shape``,
+    ``G``, ``method``; it ends after the host reads the group's errors),
+    ``ptq.emit`` once per linear, ``ptq.recompute`` once a block.
     """
     mcfg = plan.cfg
     report: dict[str, float] = {}
@@ -667,12 +684,15 @@ def _quantize_stack(
         new_period = {}
         for i, b in enumerate(pattern):
             t0 = time.monotonic()
+            recorder = obs.active()
+            snap = recorder.snapshot() if recorder is not None else None
             scope = f"{stack_name}.p{period}.b{i}"
             stats: dict[str, CalibStats] = {}
             # Capture pass: current block, current (quantized-prefix) inputs.
             # Each chunk's activations fold into Σ immediately — nothing but
             # the p×p accumulators survives this loop.
-            with capture_gram_stats(stats, mesh=mesh), capture_scope(scope):
+            with (obs.span("ptq.capture", block=scope),
+                  capture_gram_stats(stats, mesh=mesh), capture_scope(scope)):
                 for bi, x in enumerate(xs):
                     eo = None if enc_outs is None else enc_outs[bi]
                     x_chunks = _capture_chunks(x, cfg.stream_chunk)
@@ -697,14 +717,15 @@ def _quantize_stack(
             # like the capture pass, so stream_chunk bounds transient
             # activation memory in *both* passes (the stored block inputs xs
             # themselves are the pipeline's irreducible working set).
-            xs = [
-                _apply_chunked(
-                    mcfg, plan, b, new_blk, x,
-                    None if enc_outs is None else enc_outs[bi],
-                    cfg.stream_chunk,
-                )
-                for bi, x in enumerate(xs)
-            ]
+            with obs.span("ptq.recompute", block=scope):
+                xs = [
+                    _apply_chunked(
+                        mcfg, plan, b, new_blk, x,
+                        None if enc_outs is None else enc_outs[bi],
+                        cfg.stream_chunk,
+                    )
+                    for bi, x in enumerate(xs)
+                ]
             if progress_cb is not None:
                 new_keys = list(report)[n_before:]
                 errs = [report[k] for k in new_keys]
@@ -722,10 +743,14 @@ def _quantize_stack(
                     # its reported mean to 6 digits; that rounding must not
                     # reach the sensitivity signal).
                     "layer_errors": {k: float(report[k]) for k in new_keys},
+                    # Host time up to the recompute's dispatch: it waits on
+                    # the solves, not on the recompute's device time.
                     "seconds": round(time.monotonic() - t0, 3),
                 }
                 if sens:
                     rec["lambda_max"] = sens
+                if recorder is not None:
+                    rec.update(recorder.since(snap))
                 progress_cb(rec)
         quantized_periods.append(new_period)
         if cfg.emit == "fake":

@@ -38,7 +38,13 @@ a transient storage fault restarts the (deterministic) fetch instead of
 killing the run.
 
 Progress: one line + one ``progress.jsonl`` record per quantized block
-(stack, period, block index, linears solved, mean relative error, seconds).
+(stack, period, block index, linears solved, mean relative error,
+``seconds``, ``phase_s``, ``compiles``, ``compile_s``, ``cache_loads``).
+``seconds`` is host time up to the dispatch of the block's recompute, not the
+device's time for the block; ``phase_s`` holds the host seconds of each
+phase span (``ptq.capture``, ``ptq.solve``, ``ptq.emit``, ``ptq.recompute``),
+and ``compiles``/``compile_s`` the backend compiles the block waited on
+(``cache_loads`` of them read from the persistent compilation cache).
 
 End-to-end on the reduced CPU configs (quickstart-sized, ~a minute):
 
@@ -109,6 +115,7 @@ def main():
 def _run(args):
     import jax.numpy as jnp
 
+    from repro import obs
     from repro.configs import get_config
     from repro.core.solver import PTQConfig, ptq_quantize_model
     from repro.data.pipeline import DataConfig, make_batch_fn
@@ -204,17 +211,22 @@ def _run(args):
     os.makedirs(args.out_dir, exist_ok=True)
 
     def progress(rec: dict):
+        phases = " ".join(f"{k.removeprefix('ptq.')} {v:.3f}s"
+                          for k, v in rec["phase_s"].items())
         print(
             f"[{rec['stack']} p{rec['period']} b{rec['block']} "
             f"{rec['done_blocks']}/{rec['total_blocks']}] "
             f"{rec['n_linears']} linears  mean_err={rec['mean_rel_error']:.4g}  "
-            f"{rec['seconds']}s"
+            f"host to recompute dispatch {rec['seconds']}s  ({phases}; "
+            f"{rec['compiles']} compiles {rec['compile_s']:.3f}s, "
+            f"{rec['cache_loads']} from the cache)"
         )
         append_record(progress_path, rec)
 
-    qparams, report = ptq_quantize_model(
-        plan, params, calib, pcfg, mesh=mesh, progress_cb=progress
-    )
+    with obs.record():
+        qparams, report = ptq_quantize_model(
+            plan, params, calib, pcfg, mesh=mesh, progress_cb=progress
+        )
     ckpt.save_checkpoint(
         args.out_dir, manifest["step"],
         {"params": qparams},

@@ -300,3 +300,11 @@ def test_quantize_launcher_loads_params_only_checkpoint(tmp_path, monkeypatch, c
     out = capsys.readouterr().out
     assert "loaded checkpoint step 3" in out
     assert ckpt.latest_step(str(tmp_path / "out")) == 3
+    # Each block's line says what its seconds are and splits them by phase.
+    assert out.count("host to recompute dispatch") == plan.cfg.n_periods
+    assert "capture " in out and " compiles " in out
+    recs = quantize.load_progress(str(tmp_path / "out" / "progress.jsonl"))
+    assert len(recs) == plan.cfg.n_periods
+    for r in recs:
+        assert set(r["phase_s"]) == {"ptq.capture", "ptq.solve", "ptq.emit", "ptq.recompute"}
+        assert {"seconds", "compiles", "compile_s", "cache_loads"} <= set(r)
