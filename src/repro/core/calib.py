@@ -85,6 +85,7 @@ def sharded_gram(x2d: jax.Array, mesh=None, axis: Optional[str] = None) -> jax.A
     return _sharded_gram_fn(mesh, axis)(x2d)
 
 
+@jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class CalibStats:
     """Streaming Σ accumulator for one linear layer.
@@ -92,11 +93,12 @@ class CalibStats:
     ``sigma`` is the *unnormalized* Gram matrix — ``(p, p)``, or ``(E, p, p)``
     for expert-stacked MoE linears; ``n`` counts samples.  The algorithms are
     scale-invariant in Σ (β̃ in Lemma 1 uses only ratios Σ_{j,k}/Σ_{j,j}),
-    so no normalization by n is required.
+    so no normalization by n is required.  ``n`` is static pytree metadata:
+    a compiled capture returns it from the shapes it traced.
     """
 
     sigma: jax.Array  # (p, p) or (E, p, p) fp32
-    n: int = 0
+    n: int = dataclasses.field(metadata=dict(static=True), default=0)
 
     @classmethod
     def zeros(cls, p: int, experts: int = 0) -> "CalibStats":

@@ -31,6 +31,7 @@ projection ``wdt``; norms; biases; MoE router) — see DESIGN.md
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Callable, Optional
 
@@ -575,18 +576,74 @@ def _capture_chunks(x: jax.Array, chunk: int):
     return [x[i : i + chunk] for i in range(0, x.shape[0], chunk)]
 
 
+def _chunk_pairs(xs, enc_outs, chunk: int):
+    """(x chunk, enc_out chunk or None) over every calibration batch."""
+    for bi, x in enumerate(xs):
+        x_chunks = _capture_chunks(x, chunk)
+        eo = None if enc_outs is None else enc_outs[bi]
+        eo_chunks = [None] * len(x_chunks) if eo is None else _capture_chunks(eo, chunk)
+        yield from zip(x_chunks, eo_chunks)
+
+
+# The block forwards of both passes run as compiled programs, traced once per
+# (config, head plan, block kind, mesh) and argument shapes and reused for every
+# batch, block and call: run op by op, each forward re-traced its attention
+# scan while the device waited.  Module-level, so the cache outlives a call.
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3), donate_argnums=(7,))
+def _capture_program(mcfg, hp, b, mesh, p_blk, x, enc_out, sigmas):
+    """One chunk of the capture pass: the block forward with each linear's
+    input folded into its Σ.  ``sigmas`` (donated) maps linear name → Σ so
+    far, or is None (for ``eval_shape``: Σ of this chunk alone).  Returns
+    name → CalibStats of Σ so far plus this chunk's, whose static ``n`` is
+    this chunk's sample count.  Keys carry no block scope, so one trace
+    serves every block."""
+    obs.count("ptq.forward_traces")
+    chunk: dict[str, CalibStats] = {}
+    with capture_gram_stats(chunk, mesh=mesh), capture_scope(None):
+        M._block_apply(
+            mcfg, hp, b, p_blk, x,
+            mode="train", pos_ids=jnp.arange(x.shape[1]), enc_out=enc_out,
+        )
+    if sigmas is None:
+        return chunk
+    return {k: CalibStats(sigmas[k] + st.sigma, st.n) for k, st in chunk.items()}
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _block_program(mcfg, hp, b, p_blk, x, enc_out):
+    """The block forward of the recompute pass (dense or QuantizedTensor
+    weights): block output for one chunk."""
+    obs.count("ptq.forward_traces")
+    return M._block_apply(
+        mcfg, hp, b, p_blk, x,
+        mode="train", pos_ids=jnp.arange(x.shape[1]), enc_out=enc_out,
+    )[0]
+
+
+def _capture(mcfg, hp, b, p_blk, xs, enc_outs, chunk: int, mesh, scope: str):
+    """Σ of every linear of one block over all calibration chunks, as
+    {scope/name: CalibStats}."""
+    sigmas, n = None, {}
+    for xc, ec in _chunk_pairs(xs, enc_outs, chunk):
+        if sigmas is None:
+            shapes = _capture_program.eval_shape(mcfg, hp, b, mesh, p_blk, xc, ec, None)
+            sigmas = {k: jnp.zeros(st.sigma.shape, st.sigma.dtype)
+                      for k, st in shapes.items()}
+        out = _capture_program(mcfg, hp, b, mesh, p_blk, xc, ec, sigmas)
+        sigmas = {k: st.sigma for k, st in out.items()}
+        for k, st in out.items():
+            n[k] = n.get(k, 0) + st.n
+    return {f"{scope}/{k}": CalibStats(sig, n[k]) for k, sig in (sigmas or {}).items()}
+
+
 def _apply_chunked(mcfg, plan, b, blk, x, enc_out, chunk: int) -> jax.Array:
     """Forward one block over ≤chunk-sequence slices (batch dim independent)."""
-    x_chunks = _capture_chunks(x, chunk)
-    eo_chunks = (
-        [None] * len(x_chunks) if enc_out is None else _capture_chunks(enc_out, chunk)
-    )
+    eos = None if enc_out is None else [enc_out]
     outs = [
-        M._block_apply(
-            mcfg, plan.heads, b, blk, xc,
-            mode="train", pos_ids=jnp.arange(xc.shape[1]), enc_out=ec,
-        )[0]
-        for xc, ec in zip(x_chunks, eo_chunks)
+        _block_program(mcfg, plan.heads, b, blk, xc, ec)
+        for xc, ec in _chunk_pairs([x], eos, chunk)
     ]
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
 
@@ -618,8 +675,10 @@ def ptq_quantize_model(
     its recompute: it includes the waits on the solves, not the recompute's
     device time, so it is not the device's time for the block.  Inside
     ``obs.record()`` the dict also holds ``phase_s`` (host seconds in each
-    ``ptq.*`` span of the block) and the block's ``compiles``/``compile_s``
-    (``cache_loads`` of them read from the persistent compilation cache).
+    ``ptq.*`` span of the block), the block's ``compiles``/``compile_s``
+    (``cache_loads`` of them read from the persistent compilation cache) and
+    ``ptq.forward_traces``, the block forwards it traced (0 once each block
+    kind and chunk shape has been seen in the process).
 
     Phase spans (``repro.obs``), each with ``block=<scope>``: ``ptq.capture``
     once a block, ``ptq.solve`` once per same-shape group (with ``shape``,
@@ -687,26 +746,15 @@ def _quantize_stack(
             recorder = obs.active()
             snap = recorder.snapshot() if recorder is not None else None
             scope = f"{stack_name}.p{period}.b{i}"
-            stats: dict[str, CalibStats] = {}
+            obs.count("ptq.forward_traces", 0)  # reported when nothing traces
             # Capture pass: current block, current (quantized-prefix) inputs.
-            # Each chunk's activations fold into Σ immediately — nothing but
-            # the p×p accumulators survives this loop.
-            with (obs.span("ptq.capture", block=scope),
-                  capture_gram_stats(stats, mesh=mesh), capture_scope(scope)):
-                for bi, x in enumerate(xs):
-                    eo = None if enc_outs is None else enc_outs[bi]
-                    x_chunks = _capture_chunks(x, cfg.stream_chunk)
-                    eo_chunks = (
-                        [None] * len(x_chunks)
-                        if eo is None
-                        else _capture_chunks(eo, cfg.stream_chunk)
-                    )
-                    for xc, ec in zip(x_chunks, eo_chunks):
-                        pos = jnp.arange(xc.shape[1])
-                        M._block_apply(
-                            mcfg, plan.heads, b, p_period[f"b{i}"], xc,
-                            mode="train", pos_ids=pos, enc_out=ec,
-                        )
+            # Each chunk's activations fold into Σ inside its compiled
+            # forward — nothing but the p×p accumulators survives this loop.
+            with obs.span("ptq.capture", block=scope):
+                stats = _capture(
+                    mcfg, plan.heads, b, p_period[f"b{i}"], xs, enc_outs,
+                    cfg.stream_chunk, mesh, scope,
+                )
             n_before = len(report)
             sens: dict[str, float] = {}
             new_blk = _quantize_block(

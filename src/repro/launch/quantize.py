@@ -39,12 +39,14 @@ killing the run.
 
 Progress: one line + one ``progress.jsonl`` record per quantized block
 (stack, period, block index, linears solved, mean relative error,
-``seconds``, ``phase_s``, ``compiles``, ``compile_s``, ``cache_loads``).
+``seconds``, ``phase_s``, ``compiles``, ``compile_s``, ``cache_loads``,
+``ptq.forward_traces``).
 ``seconds`` is host time up to the dispatch of the block's recompute, not the
 device's time for the block; ``phase_s`` holds the host seconds of each
 phase span (``ptq.capture``, ``ptq.solve``, ``ptq.emit``, ``ptq.recompute``),
 and ``compiles``/``compile_s`` the backend compiles the block waited on
-(``cache_loads`` of them read from the persistent compilation cache).
+(``cache_loads`` of them read from the persistent compilation cache);
+``ptq.forward_traces`` counts the block forwards traced for the block.
 
 End-to-end on the reduced CPU configs (quickstart-sized, ~a minute):
 
@@ -219,7 +221,8 @@ def _run(args):
             f"{rec['n_linears']} linears  mean_err={rec['mean_rel_error']:.4g}  "
             f"host to recompute dispatch {rec['seconds']}s  ({phases}; "
             f"{rec['compiles']} compiles {rec['compile_s']:.3f}s, "
-            f"{rec['cache_loads']} from the cache)"
+            f"{rec['cache_loads']} from the cache; "
+            f"{rec['ptq.forward_traces']} block forwards traced)"
         )
         append_record(progress_path, rec)
 

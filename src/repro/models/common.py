@@ -186,7 +186,9 @@ def capture_gram_stats(stats: dict, mesh=None):
     within: each call folds its activations into the layer's Σ = XXᵀ on the
     spot (``p²`` fp32 per linear, DESIGN.md §Streaming-solver) — raw
     activations are never retained.  Under a mesh, row contraction happens
-    shard-locally with a psum (calib.sharded_gram).  Eager-only."""
+    shard-locally with a psum (calib.sharded_gram).  The dict may also be
+    opened inside a trace that returns it: the PTQ driver's compiled capture
+    (core/solver._capture_program) does, once per chunk."""
     prev = getattr(_capture_state, "stats", None)
     prev_mesh = getattr(_capture_state, "stats_mesh", None)
     _capture_state.stats = stats

@@ -68,9 +68,10 @@ class Recorder:
         return len(self.spans), dict(self.counters)
 
     def since(self, snap: tuple) -> dict:
-        """Host seconds by span name of the spans opened since ``snap``, and
-        the compiles counted since (``cache_loads`` of them were read from the
-        persistent compilation cache)."""
+        """Host seconds by span name of the spans opened since ``snap``, the
+        compiles counted since (``cache_loads`` of them were read from the
+        persistent compilation cache), and what every other counter added
+        since, under its own name."""
         n, counters = snap
         phase_s: dict[str, float] = {}
         for s in self.spans[n:]:
@@ -79,8 +80,10 @@ class Recorder:
         def grew(k):
             return self.counters.get(k, 0) - counters.get(k, 0)
 
-        return {"phase_s": phase_s, "compiles": int(grew("compiles")),
-                "compile_s": float(grew("compile_s")), "cache_loads": int(grew("cache_loads"))}
+        out = {"phase_s": phase_s, "compiles": int(grew("compiles")),
+               "compile_s": float(grew("compile_s")), "cache_loads": int(grew("cache_loads"))}
+        out.update({k: grew(k) for k in self.counters if k not in out})
+        return out
 
     def _mark(self, label: str):
         fn = _mark_fns.get(label)

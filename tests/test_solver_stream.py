@@ -13,12 +13,16 @@ vmapped calls.  These tests pin the refactor to the old semantics:
   2-device shard_map run is skip-guarded on jax.device_count()).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.configs import get_config
+from repro.core import solver
 from repro.core.calib import CalibStats, sharded_gram
 from repro.core.solver import (
     PTQConfig,
@@ -150,9 +154,25 @@ def test_moe_vmapped_experts_match_per_expert_loop():
     assert checked >= plan.cfg.n_experts
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _ref_block_forward(mcfg, hp, b, p_blk, x):
+    """The reference engine's own compiled block forward: Σ rebuilt from the
+    raw linear inputs it records (keyed by linear name), and the block
+    output."""
+    records = {}
+    with capture_linear_inputs(records):
+        y = M._block_apply(
+            mcfg, hp, b, p_blk, x, mode="train", pos_ids=jnp.arange(x.shape[1]),
+        )[0]
+    return {k: _sigma_from_records(v) for k, v in records.items()}, y
+
+
 def test_engine_report_matches_record_based_reference():
     """ISSUE 1 acceptance: streaming+batched engine reports == a record-based
-    sequential engine within 1e-4 on a reduced config."""
+    sequential engine within 1e-4 on a reduced config.  Both engines compile
+    their block forwards, so what is compared is streaming Σ and grouped
+    solves against records and sequential solves, not compiled against
+    op-by-op rounding."""
     plan, params, calib = _small(d_model=96, head_dim=24, d_ff=192, n_periods=2)
     cfg = PTQConfig(method="quantease", spec=GridSpec(bits=3), iterations=6)
     _, report = ptq_quantize_model(plan, params, calib, cfg)
@@ -167,30 +187,23 @@ def test_engine_report_matches_record_based_reference():
         p_period = jax.tree.map(lambda a: a[period], stack)
         for i, b in enumerate(mcfg.pattern):
             scope = f"dec.p{period}.b{i}"
-            records = {}
-            with capture_linear_inputs(records), capture_scope(scope):
-                for x in xs:
-                    M._block_apply(
-                        mcfg, plan.heads, b, p_period[f"b{i}"], x,
-                        mode="train", pos_ids=jnp.arange(x.shape[1]),
-                    )
+            sigmas = {}
+            for x in xs:
+                sig, _ = _ref_block_forward(mcfg, plan.heads, b, p_period[f"b{i}"], x)
+                for name, s in sig.items():
+                    key = f"{scope}/{name}"
+                    sigmas[key] = s if key not in sigmas else sigmas[key] + s
             new_blk = dict(p_period[f"b{i}"])
             for name, w in p_period[f"b{i}"].items():
                 key = f"{scope}/{name}"
-                if name not in QUANTIZABLE or key not in records:
+                if name not in QUANTIZABLE or key not in sigmas:
                     continue
-                sigma = _sigma_from_records(records[key])
+                sigma = sigmas[key]
                 w2d = w.reshape(sigma.shape[0], -1).T.astype(jnp.float32)
                 w_hat, _, _ = _quantize_one(w2d, sigma, cfg)
                 ref_report[key] = float(relative_error(w2d, w_hat, sigma))
                 new_blk[name] = w_hat.T.reshape(w.shape).astype(w.dtype)
-            xs = [
-                M._block_apply(
-                    mcfg, plan.heads, b, new_blk, x,
-                    mode="train", pos_ids=jnp.arange(x.shape[1]),
-                )[0]
-                for x in xs
-            ]
+            xs = [_ref_block_forward(mcfg, plan.heads, b, new_blk, x)[1] for x in xs]
     assert set(ref_report) == set(report)
     for key in ref_report:
         assert abs(report[key] - ref_report[key]) < 1e-4, key
@@ -308,3 +321,74 @@ def test_quantize_launcher_loads_params_only_checkpoint(tmp_path, monkeypatch, c
     for r in recs:
         assert set(r["phase_s"]) == {"ptq.capture", "ptq.solve", "ptq.emit", "ptq.recompute"}
         assert {"seconds", "compiles", "compile_s", "cache_loads"} <= set(r)
+
+
+_DRIVER_ARCHS = ["phi3_mini_3_8b", "olmoe_1b_7b", "mamba2_2_7b", "jamba_1_5_large"]
+
+
+@pytest.mark.parametrize("arch", _DRIVER_ARCHS)
+def test_warm_driver_compiles_and_traces_no_block_forward(arch):
+    """The block forwards of both passes are compiled once per block kind and
+    chunk shape: a second call compiles nothing and traces no forward, in
+    every block (dense, MoE, mamba, hybrid; packed weights in the recompute)."""
+    plan, params, calib = _small(arch)
+    cfg = PTQConfig(method="rtn", spec=GridSpec(bits=4), emit="qt")
+    ptq_quantize_model(plan, params, calib, cfg)  # warm
+    seen = []
+    with obs.record():
+        ptq_quantize_model(plan, params, calib, cfg, progress_cb=seen.append)
+    assert len(seen) == plan.cfg.n_periods * len(plan.cfg.pattern)
+    assert [(r["compiles"], r["ptq.forward_traces"]) for r in seen] == [(0, 0)] * len(seen)
+
+
+def _compiled_block_parity(arch):
+    """The driver's compiled block forward equals the op-by-op one bit for
+    bit, and its compiled Σ matches eager capture_gram_stats; run where XLA
+    may not keep intermediates wider than their dtype."""
+    plan, params, calib = _small(arch)
+    mcfg, hp = plan.cfg, plan.heads
+    xs = [M._embed_tokens(plan, params, c["tokens"]) for c in calib]
+    p_period = jax.tree.map(lambda a: a[0], params["dec"])
+    for i, b in enumerate(mcfg.pattern):
+        p_blk = p_period[f"b{i}"]
+        got = solver._capture(mcfg, hp, b, p_blk, xs, None, 0, None, "s")
+        ref = {}
+        with capture_gram_stats(ref), capture_scope("s"):
+            outs = [
+                M._block_apply(mcfg, hp, b, p_blk, x, mode="train",
+                               pos_ids=jnp.arange(x.shape[1]))[0]
+                for x in xs
+            ]
+        assert ref and set(got) == set(ref)
+        for key, st in ref.items():
+            scale = float(jnp.max(jnp.abs(st.sigma))) + 1e-9
+            assert float(jnp.max(jnp.abs(got[key].sigma - st.sigma))) / scale < 1e-5, key
+            assert got[key].n == st.n, key
+        for x, y in zip(xs, outs):
+            y_prog = solver._block_program(mcfg, hp, b, p_blk, x, None)
+            assert y_prog.dtype == y.dtype
+            np.testing.assert_array_equal(np.asarray(y_prog), np.asarray(y))
+        xs = outs
+
+
+@pytest.mark.parametrize("arch", ["phi3_mini_3_8b", "olmoe_1b_7b", "mamba2_2_7b"])
+def test_compiled_block_forward_parity_subprocess(arch):
+    """Subprocess: XLA_FLAGS must be set before jax initializes."""
+    import os
+    import subprocess
+    import sys
+
+    script = (
+        "import sys; sys.path.insert(0,'src'); sys.path.insert(0,'.');"
+        "from tests.test_solver_stream import _compiled_block_parity as t;"
+        f"t({arch!r}); print('OK')"
+    )
+    flags = (os.environ.get("XLA_FLAGS", "") + " --xla_allow_excess_precision=false").strip()
+    root = os.path.dirname(os.path.dirname(__file__))
+    r = subprocess.run(
+        [sys.executable, "-c", script],
+        capture_output=True, text=True, cwd=root,
+        env=dict(os.environ, PYTHONPATH="src", XLA_FLAGS=flags), timeout=300,
+    )
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "OK" in r.stdout

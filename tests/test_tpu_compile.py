@@ -133,3 +133,53 @@ def test_paged_attention_compiles(one_chip, kv):
         )
 
     _compile(fn, *args, scales, scales)
+
+
+def _block_structs(sharding):
+    """One phi3 block's dense parameters, an 8 × 2048 chunk of its input,
+    and the plan, config and block kind the driver's programs take."""
+    from repro.models import make_plan
+    from repro.models.model import param_shapes
+
+    plan = make_plan(CFG)
+    dec = param_shapes(plan)["dec"]
+    p_blk = jax.tree.map(lambda a: _s(sharding, a.shape[1:], a.dtype), dec)["b0"]
+    x = _s(sharding, (8, 2048, D), jnp.bfloat16)
+    return plan, CFG.pattern[0], p_blk, x
+
+
+def test_ptq_block_programs_compile(one_chip, monkeypatch):
+    """The PTQ driver's capture and recompute programs at phi3 widths: the
+    capture aliases its donated Σ accumulators (not held twice), and the
+    recompute over packed 4-bit weights keeps the Mosaic GEMM."""
+    from repro.core import solver
+    from repro.quant import GridSpec, compute_grid
+
+    plan, b, p_blk, x = _block_structs(one_chip)
+    hp = plan.heads
+    sig = solver._capture_program.eval_shape(CFG, hp, b, None, p_blk, x, None, None)
+    sigmas = {k: _s(one_chip, st.sigma.shape) for k, st in sig.items()}
+    assert len(sigmas) == 7
+    sigma_bytes = sum(4 * s.size for s in sigmas.values())
+    assert sigma_bytes == 494_927_872
+    cap = solver._capture_program.lower(CFG, hp, b, None, p_blk, x, None, sigmas).compile()
+    cap_mem = cap.memory_analysis()
+    assert cap_mem.alias_size_in_bytes >= sigma_bytes
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)  # trace as on the chip
+    cfg = solver.PTQConfig(spec=GridSpec(bits=4), emit="qt")
+
+    def emit(w, p_in):
+        w2d = solver._to_2d(w, p_in)
+        return solver._emit_leaf(w2d, None, w, cfg, compute_grid(w2d, cfg.spec))
+
+    qblk = dict(p_blk)
+    for name, s in sigmas.items():
+        qt = jax.eval_shape(functools.partial(emit, p_in=s.shape[-1]), p_blk[name])
+        assert qt.packed
+        qblk[name] = jax.tree.map(lambda a: _s(one_chip, a.shape, a.dtype), qt)
+    rec = solver._block_program.lower(CFG, hp, b, qblk, x, None).compile()
+    assert "tpu_custom_call" in rec.as_text()
+    print(f"capture temp {cap_mem.temp_size_in_bytes} B, alias "
+          f"{cap_mem.alias_size_in_bytes} B; recompute (packed 4-bit) temp "
+          f"{rec.memory_analysis().temp_size_in_bytes} B")
