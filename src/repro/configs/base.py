@@ -20,7 +20,7 @@ __all__ = ["BlockDef", "ModelConfig", "register", "get_config", "list_configs", 
 
 @dataclasses.dataclass(frozen=True)
 class BlockDef:
-    kind: str = "attn"  # "attn" | "mamba"
+    kind: str = "attn"  # "attn" | "mamba" | "conv" (gated short convolution)
     mlp: str = "dense"  # "dense" | "moe" | "none"
     window: Optional[int] = None  # sliding-window size (None = full)
     causal: bool = True  # False in encoder stacks
@@ -56,12 +56,15 @@ class ModelConfig:
     top_k: int = 0
     moe_d_ff: int = 0  # 0 → d_ff
     router_norm_topk: bool = True
+    router_score: str = "softmax"  # "softmax" | "sigmoid" (scores per expert)
+    router_bias: bool = False  # learned per-expert bias added for selection only
+    qk_norm: bool = False  # per-head RMSNorm on q and k before RoPE
     # Mamba2 (SSD)
     ssm_state: int = 128
     ssm_headdim: int = 64
     ssm_expand: int = 2
     ssm_ngroups: int = 1
-    ssm_conv: int = 4
+    ssm_conv: int = 4  # depthwise causal conv width (mamba and short-conv blocks)
     # enc-dec (whisper)
     enc_pattern: tuple = ()
     n_enc_periods: int = 0
@@ -80,6 +83,16 @@ class ModelConfig:
     @property
     def n_layers(self) -> int:
         return self.n_periods * len(self.pattern)
+
+    def reduced_stack(self) -> dict:
+        """``pattern`` and ``n_periods`` of this stack cut for a small run:
+        two periods of a repeating pattern; an unrolled one (one period,
+        the published layer list) its shortest prefix that holds every
+        distinct block."""
+        if self.n_periods > 1:
+            return dict(pattern=self.pattern, n_periods=2)
+        n = 1 + max(self.pattern.index(b) for b in self.pattern)
+        return dict(pattern=self.pattern[:n], n_periods=1)
 
     @property
     def d_inner(self) -> int:
@@ -119,6 +132,11 @@ def _mlp_params(cfg: ModelConfig, d_ff: int) -> int:
     return (2 * d * d_ff if cfg.gated_mlp else d * d_ff) + d_ff * d
 
 
+def _conv_params(cfg: ModelConfig) -> int:
+    d = cfg.d_model
+    return 3 * d * d + d * cfg.ssm_conv + d * d  # conv_in, depthwise conv, conv_out
+
+
 def _mamba_params(cfg: ModelConfig) -> int:
     d = cfg.d_model
     d_in_proj = 2 * cfg.d_inner + 2 * cfg.ssm_ngroups * cfg.ssm_state + cfg.ssm_nheads
@@ -139,8 +157,12 @@ def _count_params(cfg: ModelConfig, active_only: bool = False) -> int:
         c = 0
         if b.kind == "attn":
             c += _attn_params(cfg) + cfg.d_model  # + ln
+            if cfg.qk_norm:
+                c += 2 * cfg.hd
             if b.cross:
                 c += _attn_params(cfg, cross=True) + cfg.d_model
+        elif b.kind == "conv":
+            c += _conv_params(cfg) + cfg.d_model
         else:
             c += _mamba_params(cfg) + cfg.d_model
         if b.mlp == "dense":
@@ -148,6 +170,8 @@ def _count_params(cfg: ModelConfig, active_only: bool = False) -> int:
         elif b.mlp == "moe":
             e = cfg.top_k if active_only else cfg.n_experts
             c += cfg.d_model * cfg.n_experts  # router
+            if cfg.router_bias:
+                c += cfg.n_experts
             c += e * _mlp_params(cfg, cfg.moe_ff) + cfg.d_model
         return c
 
@@ -169,6 +193,7 @@ ARCH_IDS = (
     "mixtral_8x22b",
     "mamba2_2_7b",
     "llava_next_34b",
+    "lfm2_8b_a1b",
 )
 
 _REGISTRY: dict[str, ModelConfig] = {}

@@ -12,8 +12,9 @@ Gram matrix inside a ``shard_map`` and a ``psum`` makes it global
 to the plain local matmul.
 
 MoE layers carry one Σ per expert: a ``CalibStats`` whose ``sigma`` has a
-leading expert axis ``(E, p, p)``, updated from dispatch-table activations
-``(E, C, p)`` in one einsum (see DESIGN.md §Streaming-solver).
+leading expert axis ``(E, p, p)`` and whose ``rows`` counts the routed
+copies each expert's Σ holds, updated from rows grouped by expert in one
+grouped product (see DESIGN.md §Streaming-solver).
 """
 
 from __future__ import annotations
@@ -94,16 +95,21 @@ class CalibStats:
     for expert-stacked MoE linears; ``n`` counts samples.  The algorithms are
     scale-invariant in Σ (β̃ in Lemma 1 uses only ratios Σ_{j,k}/Σ_{j,j}),
     so no normalization by n is required.  ``n`` is static pytree metadata:
-    a compiled capture returns it from the shapes it traced.
+    a compiled capture returns it from the shapes it traced.  For expert
+    stats ``n`` counts the copies the router sent and ``rows`` (E,) those
+    each expert's Σ holds; ``n − Σ rows`` were dropped over capacity.
     """
 
     sigma: jax.Array  # (p, p) or (E, p, p) fp32
     n: int = dataclasses.field(metadata=dict(static=True), default=0)
+    rows: Optional[jax.Array] = None  # (E,) int32 for expert stats
 
     @classmethod
     def zeros(cls, p: int, experts: int = 0) -> "CalibStats":
-        shape = (experts, p, p) if experts else (p, p)
-        return cls(sigma=jnp.zeros(shape, jnp.float32), n=0)
+        if experts:
+            return cls(jnp.zeros((experts, p, p), jnp.float32), 0,
+                       jnp.zeros((experts,), jnp.int32))
+        return cls(sigma=jnp.zeros((p, p), jnp.float32), n=0)
 
     @property
     def p(self) -> int:
@@ -124,17 +130,20 @@ class CalibStats:
             sigma=self.sigma + sharded_gram(x2, mesh), n=self.n + x2.shape[0]
         )
 
-    def update_expert_tokens(self, x_experts: jax.Array) -> "CalibStats":
-        """x_experts: (E, C, p) dispatch-table activations (MoE path).
-
-        One einsum accumulates all per-expert Gram matrices; dropped slots
-        are zero rows and contribute nothing.
-        """
-        x32 = x_experts.astype(jnp.float32)
-        return CalibStats(
-            sigma=self.sigma + jnp.einsum("ecd,ecf->edf", x32, x32),
-            n=self.n + x_experts.shape[1],
+    def update_expert_rows(self, x_rows: jax.Array, group_sizes: jax.Array,
+                           kept: jax.Array, routed: int) -> "CalibStats":
+        """x_rows: (R, p) rows grouped by expert, group e being
+        ``group_sizes[e]`` consecutive rows (MoE path).  One grouped product
+        (``ragged_dot_general``, ragged over the contracted rows) gives every
+        expert's Gram matrix; ``kept[e]`` of its rows are routed copies,
+        out of ``routed`` the router sent."""
+        dn = jax.lax.RaggedDotDimensionNumbers(
+            dot_dimension_numbers=(((0,), (0,)), ((), ())),
+            lhs_ragged_dimensions=[0], rhs_group_dimensions=[],
         )
+        g = jax.lax.ragged_dot_general(x_rows, x_rows, group_sizes, dn,
+                                       preferred_element_type=jnp.float32)
+        return CalibStats(self.sigma + g, self.n + routed, self.rows + kept)
 
 
 def damp_sigma(sigma: jax.Array, percdamp: float = 0.01) -> jax.Array:

@@ -14,7 +14,10 @@ DESIGN.md §Streaming-solver:
   * **batched solves**: same-shape captured linears of a block — and all E
     experts of an MoE matrix — are stacked and solved by a single vmapped
     ``quantease_quantize``/``gptq_quantize`` call instead of sequential
-    Python loops (layer independence, as CDQuant exploits for parallel CD),
+    Python loops (layer independence, as CDQuant exploits for parallel CD);
+    an expert's ``w_gate`` and ``w_up`` read one input and one Σ, so they
+    stack along their output rows (rows are independent in every batched
+    method) and the gate/up group is E solves of (2·F, d), not 2·E,
   * **mesh sharding** (``ptq_quantize_model(..., mesh=...)``): calibration
     Gram accumulation is data-sharded with a psum (calib.sharded_gram), and
     the CD solve shard_maps over the independent q (output-row) dimension;
@@ -24,8 +27,8 @@ DESIGN.md §Streaming-solver:
 
 Quantized leaf set: every matmul the model zoo routes through
 ``apply_linear`` except numerically-critical small tensors (mamba Δ
-projection ``wdt``; norms; biases; MoE router) — see DESIGN.md
-§Arch-applicability.
+projection ``wdt``; the short conv's taps; norms; biases; MoE router and
+its selection bias) — see DESIGN.md §Arch-applicability.
 """
 
 from __future__ import annotations
@@ -59,10 +62,12 @@ __all__ = ["LayerSpec", "PTQConfig", "ptq_quantize_model", "QUANTIZABLE"]
 QUANTIZABLE = {
     "wq", "wk", "wv", "wo", "wq_c", "wk_c", "wv_c", "wo_c",
     "wg", "wu", "wd",
-    "wz", "wx", "wbc", "out_proj",
+    "wz", "wx", "wbc", "out_proj", "conv_in", "conv_out",
     "w_gate", "w_up", "w_down",
 }
 _MOE_NAMES = {"w_gate", "w_up", "w_down"}
+# Leaves whose input is another leaf's: their Σ is captured once, under it.
+_SHARED_SIGMA = {"w_up": "w_gate"}
 
 # Methods batchable with a single vmapped call *and* row-shardable under a
 # mesh.  qe_outlier/qe_outlier_struct also batch (one vmapped fused-engine
@@ -108,8 +113,7 @@ class PTQConfig:
     # Streaming capture: feed calibration batches through the capture pass in
     # chunks of this many sequences (0 = whole batch at once) so transient
     # activation memory is bounded independently of the calibration set size.
-    # Dense Σ is chunk-invariant; MoE dispatch capacity is per-forward, so
-    # chunking can shift overflow drops and perturb per-expert Σ slightly.
+    # Σ is chunk-invariant, per-expert Σ too (the capture routes dropless).
     stream_chunk: int = 0
     # Shard the CD solve over output rows (and Gram accumulation over data)
     # when a mesh is passed to ptq_quantize_model.
@@ -287,12 +291,13 @@ def _solve_batched(w3: jax.Array, sig3: jax.Array, cfg: PTQConfig, grid3):
 
 
 def _solve_group(w3: jax.Array, sig3: jax.Array, cfg: PTQConfig, mesh):
-    """Solve G stacked same-shape layers; returns (w_hat (G,q,p), hs, grids).
+    """Solve G stacked same-shape layers; returns (w_hat (G,q,p), hs, grid3).
 
     Batchable methods go through one vmapped (optionally row-sharded) call;
     outlier-aware methods run per-layer inside the same interface so the
-    grouped driver upstream stays method-agnostic.  ``grids`` is a per-slice
-    list of the Grid each solve quantized onto (None where unavailable).
+    grouped driver upstream stays method-agnostic.  ``grid3`` is the batched
+    Grid (leaves (G, q, n_groups)) the solves quantized onto, or None where
+    the method has none (AWQ, SpQR).
     """
     G = w3.shape[0]
     if cfg.method in _BATCHED_METHODS:
@@ -302,8 +307,7 @@ def _solve_group(w3: jax.Array, sig3: jax.Array, cfg: PTQConfig, mesh):
             w_hat = _shard_rows(solve, w3, sig3, grid3, mesh)
         else:
             w_hat = solve(w3, sig3, grid3)
-        grids = [jax.tree.map(lambda a: a[g], grid3) for g in range(G)]
-        return w_hat, [None] * G, grids
+        return w_hat, [None] * G, grid3
     if cfg.method in ("qe_outlier", "qe_outlier_struct"):
         # Fused outlier engine batches like everything else: one vmapped
         # solve per same-shape group.  (Never row-sharded: the unstructured
@@ -321,15 +325,13 @@ def _solve_group(w3: jax.Array, sig3: jax.Array, cfg: PTQConfig, mesh):
             use_kernel=cfg.use_kernel,
             matmul_dtype=cfg.matmul_dtype,
         )
-        grids = [jax.tree.map(lambda a: a[g], res.grid) for g in range(G)]
-        return res.w_hat, [res.h[g] for g in range(G)], grids
-    outs, hs, grids = [], [], []
+        return res.w_hat, [res.h[g] for g in range(G)], res.grid
+    outs, hs = [], []
     for g in range(G):
-        w_hat, h, grid = _quantize_one(w3[g], sig3[g], cfg)
+        w_hat, h, _ = _quantize_one(w3[g], sig3[g], cfg)  # awq, spqr: no grid
         outs.append(w_hat)
         hs.append(h)
-        grids.append(grid)
-    return jnp.stack(outs), hs, grids
+    return jnp.stack(outs), hs, None
 
 
 def _shard_rows(solve: Callable, w3: jax.Array, sig3: jax.Array, grid3, mesh):
@@ -433,35 +435,50 @@ def _emit_leaf(w_hat, h, like, cfg: PTQConfig, grid=None):
 
 @dataclasses.dataclass
 class _Item:
-    """One captured linear flattened to solver layout."""
+    """Captured linears that share one Σ, in solver layout: one leaf, or an
+    expert layer's ``w_gate`` and ``w_up`` stacked along their output rows."""
 
-    name: str  # leaf name in the block param dict
-    key: str  # report key (scope/name[, .e{i} appended per expert])
-    w3: jax.Array  # (G, q, p) — G=1 for dense linears, G=E for MoE
+    names: tuple  # leaf names in the block param dict
+    qs: tuple  # output rows of each leaf (its share of w3's q)
+    scope: str
+    sigma_key: str  # the linear whose input Σ this is
+    w3: jax.Array  # (G, Σqs, p) — G=1 for dense linears, G=E for MoE
     sig3: jax.Array  # (G, p, p)
-    like: jax.Array  # original leaf (or one expert's leaf) for reshaping
+    likes: tuple  # each original leaf (one expert's leaf for MoE), for reshaping
     moe: bool
 
+    def key(self, name: str) -> str:
+        return f"{self.scope}/{name}"
 
-def _collect_items(p_blk: dict, stats: dict, scope: str) -> list[_Item]:
-    items = []
+
+def _collect_items(p_blk: dict, stats: dict, scope: str, cfg: PTQConfig) -> list[_Item]:
+    items: list[_Item] = []
     for name, w in p_blk.items():
-        key = f"{scope}/{name}"
-        if name not in QUANTIZABLE or key not in stats:
+        src = _SHARED_SIGMA.get(name, name)
+        if name not in QUANTIZABLE or f"{scope}/{src}" not in stats:
             continue
-        st: CalibStats = stats[key]
+        st: CalibStats = stats[f"{scope}/{src}"]
         if name in _MOE_NAMES:
             # w: (E, d_in, d_out); st.sigma: (E, p, p) — already stacked.
-            E = w.shape[0]
             w3 = jax.vmap(lambda we: we.reshape(w.shape[1], -1).T)(w).astype(
                 jnp.float32
             )
-            items.append(_Item(name, key, w3, st.sigma, w[0], True))
+            it = _Item((name,), (w3.shape[1],), scope, src, w3, st.sigma, (w[0],), True)
         else:
-            p = st.p
-            items.append(
-                _Item(name, key, _to_2d(w, p)[None], st.sigma[None], w, False)
-            )
+            w2 = _to_2d(w, st.p)
+            it = _Item((name,), (w2.shape[0],), scope, src, w2[None], st.sigma[None],
+                       (w,), False)
+        # Rows are independent in the batched methods: leaves of one Σ that
+        # solve alike stack along q instead of holding Σ twice.
+        eff = cfg.for_layer(it.key(name))
+        host = next((o for o in items if o.sigma_key == src and o.moe), None)
+        if (host is not None and eff.method in _BATCHED_METHODS
+                and cfg.for_layer(host.key(host.names[0]))._group_key() == eff._group_key()):
+            host.w3 = jnp.concatenate([host.w3, it.w3], axis=1)
+            host.names, host.qs, host.likes = (
+                host.names + it.names, host.qs + it.qs, host.likes + it.likes)
+        else:
+            items.append(it)
     return items
 
 
@@ -481,41 +498,65 @@ def _quantize_block(
     ``sens``: optional dict filled with per-layer λ_max(Σ) (same keys as
     ``report``) when ``cfg.collect_sensitivity`` is set.
     """
-    items = _collect_items(p_blk, stats, scope)
+    items = _collect_items(p_blk, stats, scope, cfg)
     groups: dict[tuple, tuple[PTQConfig, list[_Item]]] = {}
     for it in items:
-        eff = cfg.for_layer(it.key)
+        eff = cfg.for_layer(it.key(it.names[0]))
         gk = (it.w3.shape[1:], eff._group_key())
         groups.setdefault(gk, (eff, []))[1].append(it)
 
     new = dict(p_blk)
     for (shape, _), (eff, group) in groups.items():
         G_all = sum(it.w3.shape[0] for it in group)
+        experts = {"experts": it.w3.shape[0] for it in group if it.moe}
         with obs.span("ptq.solve", block=scope, shape=str(tuple(shape)), G=G_all,
-                      method=eff.method):
-            w3 = jnp.concatenate([it.w3 for it in group], axis=0)
-            sig3 = jnp.concatenate([it.sig3 for it in group], axis=0)
-            w_hat3, hs, grids = _solve_group(w3, sig3, eff, mesh)
-            # One host read per group, inside the span: it waits for the solve.
-            errs = np.asarray(relative_error(w3, _effective(w_hat3, hs), sig3))
+                      method=eff.method, **experts):
+            if len(group) == 1:  # no copy: an expert group is GBs
+                w3, sig3 = group[0].w3, group[0].sig3
+            else:
+                w3 = jnp.concatenate([it.w3 for it in group], axis=0)
+                sig3 = jnp.concatenate([it.sig3 for it in group], axis=0)
+            w_hat3, hs, grid3 = _solve_group(w3, sig3, eff, mesh)
+            errs = _leaf_errors(group, w3, _effective(w_hat3, hs), sig3)
             if cfg.collect_sensitivity and sens is not None:
                 for it in group:
                     lam = jax.vmap(outlier.power_lambda_max)(it.sig3)
-                    if it.moe:
-                        for e in range(it.sig3.shape[0]):
-                            sens[f"{it.key}.e{e}"] = float(lam[e])
-                    else:
-                        sens[it.key] = float(lam[0])
+                    for name in it.names:
+                        if it.moe:
+                            for e in range(it.sig3.shape[0]):
+                                sens[f"{it.key(name)}.e{e}"] = float(lam[e])
+                        else:
+                            sens[it.key(name)] = float(lam[0])
         off = 0
         for it in group:
             G = it.w3.shape[0]
             sl = slice(off, off + G)
-            with obs.span("ptq.emit", block=scope, linear=it.name):
-                _scatter_item(
-                    it, w_hat3[sl], hs[sl], errs[sl], new, eff, report, grids[sl]
-                )
+            grid_it = None if grid3 is None else jax.tree.map(lambda a: a[sl], grid3)
+            _scatter_item(it, w_hat3[sl], hs[sl], errs[id(it)], new, eff, report, grid_it)
             off += G
     return new
+
+
+def _leaf_errors(group, w3, eff3, sig3) -> dict:
+    """Relative error of every leaf of the group: {id(item): [(G,) per
+    leaf]}, from one read of the host per row layout (one for a group of
+    single leaves); the read waits for the solve."""
+    layouts = {}
+    for it in group:
+        bounds = np.cumsum((0,) + it.qs)
+        layouts.update({(int(a), int(b)): None for a, b in zip(bounds[:-1], bounds[1:])})
+    q = w3.shape[1]
+    for a, b in layouts:
+        rows = (slice(None),) if (a, b) == (0, q) else (slice(None), slice(a, b))
+        layouts[(a, b)] = np.asarray(relative_error(w3[rows], eff3[rows], sig3))
+    out, off = {}, 0
+    for it in group:
+        G = it.w3.shape[0]
+        bounds = np.cumsum((0,) + it.qs)
+        out[id(it)] = [layouts[(int(a), int(b))][off:off + G]
+                       for a, b in zip(bounds[:-1], bounds[1:])]
+        off += G
+    return out
 
 
 def _effective(w_hat3, hs):
@@ -527,27 +568,38 @@ def _effective(w_hat3, hs):
 
 
 def _scatter_item(
-    it: _Item, w_hat, hs, errs, new: dict, cfg: PTQConfig, report: dict, grids
+    it: _Item, w_hat, hs, errs, new: dict, cfg: PTQConfig, report: dict, grid3
 ):
-    if it.moe:
-        for e in range(w_hat.shape[0]):
-            report[f"{it.key}.e{e}"] = float(errs[e])
-        if cfg.emit == "fake":
-            new[it.name] = jnp.stack(
-                [
-                    _from_2d(w if h is None else w + h, it.like)
-                    for w, h in zip(w_hat, hs)
-                ]
-            ).astype(new[it.name].dtype)
-        else:
-            qts = [
-                _emit_leaf(w, h, it.like, cfg, grid)
-                for w, h, grid in zip(w_hat, hs, grids)
-            ]
-            new[it.name] = jax.tree.map(lambda *ls: jnp.stack(ls), *qts)
-    else:
-        report[it.key] = float(errs[0])
-        new[it.name] = _emit_leaf(w_hat[0], hs[0], it.like, cfg, grids[0])
+    """Emit each leaf of ``it`` into ``new`` and its errors into ``report``.
+    Expert leaves emit all E experts in one vmapped call."""
+    h3 = None if all(h is None for h in hs) else jnp.stack(hs)
+    off = 0
+    for name, q, like, err in zip(it.names, it.qs, it.likes, errs):
+        multi = len(it.names) > 1
+        rows = slice(off, off + q)
+        w_p = w_hat[:, rows] if multi else w_hat
+        h_p = None if h3 is None else (h3[:, rows] if multi else h3)
+        g_p = grid3 if grid3 is None or not multi else jax.tree.map(
+            lambda a: a[:, rows], grid3)
+        off += q
+        if not it.moe:
+            report[it.key(name)] = float(err[0])
+            g0 = None if g_p is None else jax.tree.map(lambda a: a[0], g_p)
+            with obs.span("ptq.emit", block=it.scope, linear=name):
+                new[name] = _emit_leaf(w_p[0], None if h_p is None else h_p[0], like,
+                                       cfg, g0)
+            continue
+        for e in range(w_p.shape[0]):
+            report[f"{it.key(name)}.e{e}"] = float(err[e])
+        with obs.span("ptq.emit", block=it.scope, linear=name, experts=w_p.shape[0]):
+            if cfg.emit == "fake":
+                w_eff = w_p if h_p is None else w_p + h_p
+                new[name] = jnp.swapaxes(w_eff, 1, 2).reshape(
+                    (w_p.shape[0],) + like.shape).astype(new[name].dtype)
+            else:
+                new[name] = jax.vmap(
+                    lambda w, h, g: _emit_leaf(w, h, like, cfg, g)
+                )(w_p, h_p, g_p)
 
 
 # ---------------------------------------------------------------------------
@@ -592,13 +644,14 @@ def _chunk_pairs(xs, enc_outs, chunk: int):
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3), donate_argnums=(7,))
-def _capture_program(mcfg, hp, b, mesh, p_blk, x, enc_out, sigmas):
+def _capture_program(mcfg, hp, b, mesh, p_blk, x, enc_out, acc):
     """One chunk of the capture pass: the block forward with each linear's
-    input folded into its Σ.  ``sigmas`` (donated) maps linear name → Σ so
-    far, or is None (for ``eval_shape``: Σ of this chunk alone).  Returns
-    name → CalibStats of Σ so far plus this chunk's, whose static ``n`` is
-    this chunk's sample count.  Keys carry no block scope, so one trace
-    serves every block."""
+    input folded into its Σ.  ``acc`` (donated) maps linear name →
+    CalibStats so far (its ``n`` unused), or is None (for ``eval_shape``:
+    this chunk's alone).  Returns name → CalibStats of Σ (and expert
+    ``rows``) so far plus this chunk's, whose static ``n`` is this chunk's
+    sample count.  Keys carry no block scope, so one trace serves every
+    block.  MoE layers route dropless here (``_block_apply``'s default)."""
     obs.count("ptq.forward_traces")
     chunk: dict[str, CalibStats] = {}
     with capture_gram_stats(chunk, mesh=mesh), capture_scope(None):
@@ -606,9 +659,11 @@ def _capture_program(mcfg, hp, b, mesh, p_blk, x, enc_out, sigmas):
             mcfg, hp, b, p_blk, x,
             mode="train", pos_ids=jnp.arange(x.shape[1]), enc_out=enc_out,
         )
-    if sigmas is None:
+    if acc is None:
         return chunk
-    return {k: CalibStats(sigmas[k] + st.sigma, st.n) for k, st in chunk.items()}
+    return {k: CalibStats(acc[k].sigma + st.sigma, st.n,
+                          None if st.rows is None else acc[k].rows + st.rows)
+            for k, st in chunk.items()}
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2))
@@ -625,17 +680,35 @@ def _block_program(mcfg, hp, b, p_blk, x, enc_out):
 def _capture(mcfg, hp, b, p_blk, xs, enc_outs, chunk: int, mesh, scope: str):
     """Σ of every linear of one block over all calibration chunks, as
     {scope/name: CalibStats}."""
-    sigmas, n = None, {}
+    acc, n = None, {}
     for xc, ec in _chunk_pairs(xs, enc_outs, chunk):
-        if sigmas is None:
+        if acc is None:
             shapes = _capture_program.eval_shape(mcfg, hp, b, mesh, p_blk, xc, ec, None)
-            sigmas = {k: jnp.zeros(st.sigma.shape, st.sigma.dtype)
-                      for k, st in shapes.items()}
-        out = _capture_program(mcfg, hp, b, mesh, p_blk, xc, ec, sigmas)
-        sigmas = {k: st.sigma for k, st in out.items()}
+            acc = {k: jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), st)
+                   for k, st in shapes.items()}
+        out = _capture_program(mcfg, hp, b, mesh, p_blk, xc, ec,
+                               {k: dataclasses.replace(st, n=0) for k, st in acc.items()})
+        acc = out
         for k, st in out.items():
             n[k] = n.get(k, 0) + st.n
-    return {f"{scope}/{k}": CalibStats(sig, n[k]) for k, sig in (sigmas or {}).items()}
+    return {f"{scope}/{k}": dataclasses.replace(st, n=n[k]) for k, st in (acc or {}).items()}
+
+
+def _moe_counts(stats: dict) -> dict:
+    """The block's routed copies, from the Σ of its expert layers' input
+    (``w_gate``): copies the router sent, those no expert's Σ holds (over
+    capacity), and the fewest and most any expert received.  Reads the
+    host once; the capture has finished."""
+    layers = [st for k, st in stats.items() if st.rows is not None and k.endswith("/w_gate")]
+    if not layers:
+        return {}
+    kept = np.concatenate([np.asarray(st.rows) for st in layers])
+    routed = sum(st.n for st in layers)
+    out = {"moe.routed_copies": routed, "moe.dropped_copies": routed - int(kept.sum()),
+           "moe.expert_tokens_min": int(kept.min()), "moe.expert_tokens_max": int(kept.max())}
+    obs.count("moe.routed_copies", out["moe.routed_copies"])
+    obs.count("moe.dropped_copies", out["moe.dropped_copies"])
+    return out
 
 
 def _apply_chunked(mcfg, plan, b, blk, x, enc_out, chunk: int) -> jax.Array:
@@ -678,12 +751,15 @@ def ptq_quantize_model(
     ``ptq.*`` span of the block), the block's ``compiles``/``compile_s``
     (``cache_loads`` of them read from the persistent compilation cache) and
     ``ptq.forward_traces``, the block forwards it traced (0 once each block
-    kind and chunk shape has been seen in the process).
+    kind and chunk shape has been seen in the process).  A block with
+    experts adds ``moe.routed_copies``, ``moe.dropped_copies`` (also
+    counters) and ``moe.expert_tokens_min``/``_max`` (:func:`_moe_counts`).
 
     Phase spans (``repro.obs``), each with ``block=<scope>``: ``ptq.capture``
     once a block, ``ptq.solve`` once per same-shape group (with ``shape``,
     ``G``, ``method``; it ends after the host reads the group's errors),
-    ``ptq.emit`` once per linear, ``ptq.recompute`` once a block.
+    ``ptq.emit`` once per linear, ``ptq.recompute`` once a block.  On expert
+    layers ``ptq.solve`` and ``ptq.emit`` also carry ``experts=<E>``.
     """
     mcfg = plan.cfg
     report: dict[str, float] = {}
@@ -760,6 +836,7 @@ def _quantize_stack(
             new_blk = _quantize_block(
                 p_period[f"b{i}"], stats, scope, cfg, report, mesh, sens=sens
             )
+            moe = _moe_counts(stats)
             new_period[f"b{i}"] = new_blk
             # Recompute this block's outputs with quantized weights — chunked
             # like the capture pass, so stream_chunk bounds transient
@@ -797,6 +874,7 @@ def _quantize_stack(
                 }
                 if sens:
                     rec["lambda_max"] = sens
+                rec.update(moe)
                 if recorder is not None:
                     rec.update(recorder.since(snap))
                 progress_cb(rec)

@@ -15,10 +15,8 @@ Flags beyond the model/method basics:
 * ``--stream-calib N`` — feed the capture pass at most N sequences at a
   time (0 = whole calibration batch at once).  Transient activation memory
   during capture becomes O(N·seq·p) regardless of ``--calib-batches``.
-  For dense linears the accumulated Σ is identical either way; MoE layers
-  compute dispatch capacity per forward, so chunking can change which
-  overflow tokens drop and perturb the per-expert Σ slightly (same effect
-  as choosing a different calibration batch size).
+  The accumulated Σ is identical either way, per-expert Σ too: the
+  capture routes every copy to its experts (dropless).
 * ``--resume`` — report progress from a previous run's ``progress.jsonl``
   in the output dir before starting (block-level audit trail of what
   completed and the per-block error summary), then restart from scratch.
@@ -46,7 +44,10 @@ device's time for the block; ``phase_s`` holds the host seconds of each
 phase span (``ptq.capture``, ``ptq.solve``, ``ptq.emit``, ``ptq.recompute``),
 and ``compiles``/``compile_s`` the backend compiles the block waited on
 (``cache_loads`` of them read from the persistent compilation cache);
-``ptq.forward_traces`` counts the block forwards traced for the block.
+``ptq.forward_traces`` counts the block forwards traced for the block.  A
+block with routed experts adds ``moe.routed_copies``, ``moe.dropped_copies``
+(0: the capture is dropless) and ``moe.expert_tokens_min``/``_max``, the
+fewest and most routed copies an expert's Σ holds.
 
 End-to-end on the reduced CPU configs (quickstart-sized, ~a minute):
 
@@ -223,6 +224,9 @@ def _run(args):
             f"{rec['compiles']} compiles {rec['compile_s']:.3f}s, "
             f"{rec['cache_loads']} from the cache; "
             f"{rec['ptq.forward_traces']} block forwards traced)"
+            + (f"  experts: {rec['moe.routed_copies']} routed copies, "
+               f"{rec['moe.dropped_copies']} dropped, {rec['moe.expert_tokens_min']}.."
+               f"{rec['moe.expert_tokens_max']} an expert" if "moe.routed_copies" in rec else "")
         )
         append_record(progress_path, rec)
 

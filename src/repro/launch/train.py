@@ -15,13 +15,13 @@ import dataclasses
 
 def reduced(cfg):
     kw = dict(
+        **cfg.reduced_stack(),
         d_model=128,
         n_heads=4 if cfg.n_heads else 0,
         n_kv_heads=min(cfg.n_kv_heads, 2) if cfg.n_kv_heads else 0,
         head_dim=32,
         d_ff=0 if cfg.d_ff == 0 else 256,
         vocab=256,
-        n_periods=2,
         max_seq=1024,
         n_experts=4 if cfg.n_experts else 0,
         top_k=min(cfg.top_k, 2),

@@ -25,6 +25,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.dist.sharding import logical_constraint
 from repro.quant import QuantizedTensor
@@ -200,7 +201,14 @@ def capture_gram_stats(stats: dict, mesh=None):
         _capture_state.stats_mesh = prev_mesh
 
 
-def _record_linear(name, x, expert_stacked: bool = False):
+def _record_linear(name, x, expert_rows=None):
+    """Fold a linear's input into the open capture (records or Σ).
+
+    ``expert_rows = (group_sizes, kept, routed)`` marks routed-expert input:
+    ``x`` is (R, p) rows grouped by expert — group e is ``group_sizes[e]``
+    consecutive rows, ``kept[e]`` of them routed copies (the rest empty
+    capacity slots, zero rows) — out of ``routed`` (static) copies the
+    router sent.  It gets one Σ per expert (``CalibStats`` with ``rows``)."""
     if name is None:
         return
     records = getattr(_capture_state, "records", None)
@@ -210,18 +218,23 @@ def _record_linear(name, x, expert_stacked: bool = False):
     scope = getattr(_capture_state, "scope", None)
     key = f"{scope}/{name}" if scope else name
     if records is not None:
-        records.setdefault(key, []).append(
-            x if expert_stacked else x.reshape(-1, x.shape[-1])
-        )
+        if expert_rows is None:
+            records.setdefault(key, []).append(x.reshape(-1, x.shape[-1]))
+        else:  # eager only: per-expert row blocks
+            ends = np.cumsum(np.asarray(expert_rows[0]))
+            records.setdefault(key, []).append(jnp.split(x, ends[:-1]))
     if stats is not None:
         from repro.core.calib import CalibStats
 
         p = x.shape[-1]
-        if key not in stats:
-            stats[key] = CalibStats.zeros(p, experts=x.shape[0] if expert_stacked else 0)
-        if expert_stacked:
-            stats[key] = stats[key].update_expert_tokens(x)
+        if expert_rows is not None:
+            sizes, kept, routed = expert_rows
+            if key not in stats:
+                stats[key] = CalibStats.zeros(p, experts=sizes.shape[0])
+            stats[key] = stats[key].update_expert_rows(x, sizes, kept, routed)
         else:
+            if key not in stats:
+                stats[key] = CalibStats.zeros(p)
             stats[key] = stats[key].update_tokens(
                 x, mesh=getattr(_capture_state, "stats_mesh", None)
             )

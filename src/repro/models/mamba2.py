@@ -13,6 +13,10 @@ its channels never straddle shards.
 
 Decode is the O(1) recurrence: S ← exp(dtA)·S + dt·(B ⊗ x), y = C·S + D·x,
 with a rolling (conv_w−1)-deep conv state.
+
+The gated short-convolution block (LFM2's conv layers, ``kind="conv"``)
+reuses the depthwise causal conv and its rolling decode buffer:
+``B, C, x = split3(conv_in(h))``, ``conv_out(C ⊙ dwconv(B ⊙ x))``.
 """
 
 from __future__ import annotations
@@ -26,7 +30,10 @@ import jax.numpy as jnp
 from repro.dist.sharding import logical_constraint
 from repro.models.common import apply_linear, rmsnorm
 
-__all__ = ["MambaCache", "mamba_params_shape", "mamba_apply", "mamba_decode"]
+__all__ = [
+    "MambaCache", "mamba_params_shape", "mamba_apply", "mamba_decode",
+    "shortconv_apply", "shortconv_decode",
+]
 
 
 @jax.tree_util.register_dataclass
@@ -37,14 +44,14 @@ class MambaCache:
     ssm: jax.Array  # (B, nh, hd, N) fp32
 
 
-def _dw_conv(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
+def _dw_conv(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None) -> jax.Array:
     """Causal depthwise conv along axis 1.  x: (B, L, *ch), w: (*ch, K)."""
     k = w.shape[-1]
     x = jnp.pad(x, [(0, 0), (k - 1, 0)] + [(0, 0)] * (x.ndim - 2))
     out = sum(
         x[:, i : i + x.shape[1] - k + 1] * w[..., i] for i in range(k)
     )
-    return out + b
+    return out if b is None else out + b
 
 
 def _ssd_chunked(
@@ -164,8 +171,11 @@ def mamba_apply(
     chunk: int = 128,
     cache: Optional[MambaCache] = None,
     return_cache: bool = False,
+    valid_len=None,
 ):
-    """Full-sequence SSD block (train / prefill)."""
+    """Full-sequence SSD block (train / prefill).  ``valid_len`` (prefill):
+    positions from it on are padding, with Δ = 0 there, so they leave the
+    state as the last real token left it."""
     B, L, D = x.shape
     nh, hd = cfg.ssm_nheads, cfg.ssm_headdim
     G, N = cfg.ssm_ngroups, cfg.ssm_state
@@ -181,6 +191,8 @@ def mamba_apply(
     b, c = jnp.split(bcv.reshape(B, L, 2 * G, N), 2, axis=2)
 
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
+    if valid_len is not None:
+        dt = jnp.where((jnp.arange(L) < valid_len)[None, :, None], dt, 0.0)
     a = -jnp.exp(p["a_log"].astype(jnp.float32))
 
     y, h_final = _ssd_chunked(xin, dt, a, b, c, chunk=chunk)
@@ -192,15 +204,19 @@ def mamba_apply(
         return out, None
     k = cfg.ssm_conv
     new_cache = MambaCache(
-        conv_x=_last_k(xin_pre, k - 1),
-        conv_bc=_last_k(bc_pre, k - 1),
+        conv_x=_last_k(xin_pre, k - 1, valid_len),
+        conv_bc=_last_k(bc_pre, k - 1, valid_len),
         ssm=h_final,
     )
     return out, new_cache
 
 
-def _last_k(x: jax.Array, k: int) -> jax.Array:
-    return x[:, x.shape[1] - k :]
+def _last_k(x: jax.Array, k: int, end=None) -> jax.Array:
+    """The k positions before ``end`` (default: the last k), zeros before 0."""
+    if end is None:
+        return x[:, x.shape[1] - k :]
+    x = jnp.pad(x, [(0, 0), (k, 0)] + [(0, 0)] * (x.ndim - 2))
+    return jax.lax.dynamic_slice_in_dim(x, end, k, axis=1)
 
 
 def mamba_decode(p: dict, x: jax.Array, cfg, cache: MambaCache):
@@ -246,3 +262,32 @@ def mamba_decode(p: dict, x: jax.Array, cfg, cache: MambaCache):
         conv_x=conv_x_hist[:, 1:], conv_bc=conv_bc_hist[:, 1:], ssm=ssm
     )
     return out, new_cache
+
+
+def _shortconv_gate(p: dict, x: jax.Array):
+    """conv_in and the input gate: x (..., D) → (B ⊙ x, C)."""
+    bcx = apply_linear(p["conv_in"], x, name="conv_in")
+    b, c, xv = jnp.split(bcx, 3, axis=-1)
+    return b * xv, c
+
+
+def shortconv_apply(p: dict, x: jax.Array, *, return_cache: bool = False, valid_len=None):
+    """Gated short conv over a sequence (train / prefill).  x: (B, L, D)
+    post-norm input.  The conv runs in fp32 on the bf16 gated input; the
+    cache is the ``K − 1`` gated inputs before ``valid_len`` (default: the
+    last)."""
+    bx, c = _shortconv_gate(p, x)
+    conv = _dw_conv(bx.astype(jnp.float32), p["conv_w"].astype(jnp.float32))
+    out = apply_linear(p["conv_out"], c * conv.astype(x.dtype), name="conv_out")
+    if not return_cache:
+        return out, None
+    return out, {"conv": _last_k(bx, p["conv_w"].shape[-1] - 1, valid_len)}
+
+
+def shortconv_decode(p: dict, x: jax.Array, cache: dict):
+    """One-token step.  x: (B, 1, D); cache["conv"]: (B, K − 1, D)."""
+    bx, c = _shortconv_gate(p, x[:, 0])
+    hist = jnp.concatenate([cache["conv"], bx[:, None].astype(cache["conv"].dtype)], axis=1)
+    conv = jnp.einsum("btd,dt->bd", hist.astype(jnp.float32), p["conv_w"].astype(jnp.float32))
+    out = apply_linear(p["conv_out"], c * conv.astype(x.dtype), name="conv_out")
+    return out[:, None], {"conv": hist[:, 1:]}

@@ -1,7 +1,8 @@
 """Model assembly: params, train loss, prefill and decode for all families.
 
-One generic stack covers the 10 assigned architectures (configs/base.py):
-``lax.scan`` over *periods* of blocks (attn / mamba × dense / MoE / none),
+One generic stack covers the assigned architectures (configs/base.py):
+``lax.scan`` over *periods* of blocks (attn / mamba / short conv × dense /
+MoE / none),
 optional encoder stack (whisper), optional embedding prefix stub (llava).
 
 Param tree (all leaves bf16 unless noted):
@@ -46,7 +47,7 @@ from repro.models.common import (
     softcap,
 )
 from repro.models.mamba2 import MambaCache
-from repro.models.moe import moe_apply, router_aux_loss
+from repro.models.moe import MOE_TRAIN_CAPACITY, moe_apply, router_aux_loss
 
 __all__ = [
     "ModelPlan",
@@ -116,12 +117,13 @@ def make_plan(
 class _P:
     shape: tuple
     axes: tuple
-    init: str = "normal"  # normal | zeros | ones | small_normal | conv | dt | alog
+    init: str = "normal"  # normal | zeros | zeros32 | ones | small_normal | conv | dt | alog
 
     @property
     def dtype_override(self):
-        # SSM dynamics params are numerically sensitive → fp32 (DESIGN.md §5).
-        return jnp.float32 if self.init in ("dt", "alog") else None
+        # SSM dynamics params are numerically sensitive → fp32 (DESIGN.md §5),
+        # as is the router's selection bias (a bf16 bias would decide ties).
+        return jnp.float32 if self.init in ("dt", "alog", "zeros32") else None
 
 
 def _norm_def(cfg, d) -> dict:
@@ -138,6 +140,9 @@ def _attn_defs(cfg: ModelConfig, hp: HeadPlan, suffix="") -> dict:
         f"wv{suffix}": _P((d, hp.n_kv, hd), ("embed", "kv_heads", "head_dim")),
         f"wo{suffix}": _P((hp.kv_pad, hp.g_pad, hd, d), ("heads", None, None, "embed")),
     }
+    if cfg.qk_norm and not suffix:
+        defs["q_norm"] = _norm_def(cfg, hd)
+        defs["k_norm"] = _norm_def(cfg, hd)
     if cfg.qkv_bias and not suffix:
         defs["bq"] = _P((hp.kv_pad, hp.g_pad, hd), ("heads", None, None), "zeros")
         defs["bk"] = _P((hp.n_kv, hd), ("kv_heads", "head_dim"), "zeros")
@@ -165,7 +170,18 @@ def _moe_defs(cfg: ModelConfig) -> dict:
     }
     if cfg.gated_mlp:
         defs["w_up"] = _P((e, d, f), ("experts", "embed", "expert_ffn"))
+    if cfg.router_bias:
+        defs["expert_bias"] = _P((e,), (None,), "zeros32")
     return defs
+
+
+def _conv_defs(cfg: ModelConfig) -> dict:
+    d = cfg.d_model
+    return {
+        "conv_in": _P((d, 3 * d), ("embed", None)),
+        "conv_w": _P((d, cfg.ssm_conv), (None, None), "conv"),
+        "conv_out": _P((d, d), (None, "embed"), "small_normal"),
+    }
 
 
 def _mamba_defs(cfg: ModelConfig) -> dict:
@@ -200,6 +216,8 @@ def _block_defs(cfg: ModelConfig, hp: HeadPlan, b: BlockDef) -> dict:
             defs.update(_attn_defs(cfg, hp, suffix="_c"))
         if cfg.post_norms:
             defs["post_ln"] = _norm_def(cfg, d)
+    elif b.kind == "conv":
+        defs.update(_conv_defs(cfg))
     else:
         defs.update(_mamba_defs(cfg))
     if b.mlp != "none":
@@ -264,6 +282,8 @@ def _init_leaf(key, pd: _P, dtype, n_layers_total: int):
     shape = pd.shape
     if pd.init == "zeros":
         return jnp.zeros(shape, dtype)
+    if pd.init == "zeros32":
+        return jnp.zeros(shape, jnp.float32)
     if pd.init == "ones":
         return jnp.ones(shape, dtype)
     if pd.init == "normal":
@@ -317,6 +337,9 @@ def _qkv(cfg, hp: HeadPlan, p, h, suffix=""):
         q = q + p["bq"]
         k = k + p["bk"]
         v = v + p["bv"]
+    if cfg.qk_norm and not suffix:  # per head, before RoPE
+        q = apply_norm(p["q_norm"], q, cfg.norm)
+        k = apply_norm(p["k_norm"], k, cfg.norm)
     return q, _expand_kv(hp, k), _expand_kv(hp, v)
 
 
@@ -573,7 +596,7 @@ def _fill_cache(cache, k, v, window, pos_ids, kv_dtype="bf16"):
     return out
 
 
-def _mlp_sublayer(cfg, b: BlockDef, p, x, aux, dispatch_groups=1):
+def _mlp_sublayer(cfg, b: BlockDef, p, x, aux, dispatch_groups=1, moe_capacity=None):
     if b.mlp == "none":
         return x, aux
     h = apply_norm(p["ln2"], x, cfg.norm)
@@ -586,6 +609,8 @@ def _mlp_sublayer(cfg, b: BlockDef, p, x, aux, dispatch_groups=1):
             act=cfg.act,
             gated=cfg.gated_mlp,
             norm_topk=cfg.router_norm_topk,
+            score=cfg.router_score,
+            capacity_factor=moe_capacity,
             return_aux=aux is not None,
             dispatch_groups=dispatch_groups,
         )
@@ -605,7 +630,10 @@ def _mlp_sublayer(cfg, b: BlockDef, p, x, aux, dispatch_groups=1):
 
 def _block_apply(cfg, hp, b, p, x, *, mode, pos_ids, cache=None, enc_out=None,
                  decode_pos=None, aux=None, kv_dtype="bf16", dispatch_groups=1,
-                 page_table=None, page_write=None):
+                 page_table=None, page_write=None, moe_capacity=None, valid_len=None):
+    """One block.  ``moe_capacity``: the MoE capacity factor (training), or
+    None for dropless routing.  ``valid_len`` (prefill): the real tokens;
+    later ones are padding that recurrent state does not fold in."""
     if b.kind == "attn":
         x, new_cache = _attn_sublayer(
             cfg, hp, b, p, x,
@@ -613,16 +641,26 @@ def _block_apply(cfg, hp, b, p, x, *, mode, pos_ids, cache=None, enc_out=None,
             decode_pos=decode_pos, kv_dtype=kv_dtype,
             page_table=page_table, page_write=page_write,
         )
+    elif b.kind == "conv":
+        h = apply_norm(p["ln"], x, cfg.norm)
+        if mode == "decode":
+            y, new_cache = mamba2.shortconv_decode(p, h, cache)
+        else:
+            y, new_cache = mamba2.shortconv_apply(
+                p, h, return_cache=(mode == "prefill"), valid_len=valid_len
+            )
+        x = x + y
     else:
         h = apply_norm(p["ln"], x, cfg.norm)
         if mode == "decode":
             y, new_cache = mamba2.mamba_decode(p, h, cfg, cache)
         else:
             y, new_cache = mamba2.mamba_apply(
-                p, h, cfg, cache=cache, return_cache=(mode == "prefill")
+                p, h, cfg, cache=cache, return_cache=(mode == "prefill"),
+                valid_len=valid_len,
             )
         x = x + y
-    x, aux = _mlp_sublayer(cfg, b, p, x, aux, dispatch_groups)
+    x, aux = _mlp_sublayer(cfg, b, p, x, aux, dispatch_groups, moe_capacity)
     return x, new_cache, aux
 
 
@@ -646,10 +684,14 @@ def _run_stack(
     remat: bool = True,
     page_table=None,
     page_write=None,
+    moe_capacity=None,
+    valid_len=None,
 ):
     """Scan over periods.  caches: pytree stacked on leading period axis.
     ``page_table``/``page_write`` (shared across periods) switch attention
-    layers to the paged KV path."""
+    layers to the paged KV path; ``moe_capacity`` (training) caps each
+    expert's routed copies, None routes dropless; ``valid_len`` as in
+    :func:`_block_apply`."""
     cfg, hp = plan.cfg, plan.heads
     have_aux = aux is not None
 
@@ -667,6 +709,7 @@ def _run_stack(
                 decode_pos=decode_pos, aux=aux, kv_dtype=plan.kv_cache_dtype,
                 dispatch_groups=plan.dispatch_groups,
                 page_table=page_table, page_write=page_write,
+                moe_capacity=moe_capacity, valid_len=valid_len,
             )
             new_caches[f"b{i}"] = nc
         return (x, aux), new_caches
@@ -805,6 +848,7 @@ def train_loss(plan: ModelPlan, params, batch: dict) -> jax.Array:
     x, _, aux = _run_stack(
         plan, params["dec"], cfg.pattern, x,
         mode="train", pos_ids=pos, enc_out=enc_out, aux=aux0,
+        moe_capacity=MOE_TRAIN_CAPACITY,
     )
     x = apply_norm(params["final_norm"], x, cfg.norm)
 
@@ -863,6 +907,8 @@ def _block_cache_shape(plan: ModelPlan, b: BlockDef, B: int, cap: int):
             )
         return sh
     k = cfg.ssm_conv
+    if b.kind == "conv":
+        return {"conv": jax.ShapeDtypeStruct((B, k - 1, cfg.d_model), jnp.bfloat16)}
     return MambaCache(
         conv_x=jax.ShapeDtypeStruct(
             (B, k - 1, cfg.ssm_nheads, cfg.ssm_headdim), jnp.bfloat16
@@ -911,6 +957,8 @@ def cache_axes(plan: ModelPlan, seq_shard: bool = False):
     for i, b in enumerate(cfg.pattern):
         if b.kind == "attn":
             out[f"b{i}"] = attn_axes(b)
+        elif b.kind == "conv":
+            out[f"b{i}"] = {"conv": ("layers", "batch", None, None)}
         else:
             out[f"b{i}"] = MambaCache(
                 conv_x=("layers", "batch", None, "ssm_heads", None),
@@ -982,7 +1030,10 @@ def init_paged_cache(plan: ModelPlan, n_pages: int, page_size: int):
 
 
 def prefill(plan: ModelPlan, params, batch: dict, cache):
-    """Full-sequence forward filling `cache`; returns (last_logits, cache)."""
+    """Full-sequence forward filling `cache`; returns (last_logits, cache).
+    ``batch["length"]`` (optional, traced): the real tokens of a
+    right-padded batch — recurrent state (mamba, short conv) stops there;
+    attention caches hold the pads, which decode's length mask hides."""
     cfg = plan.cfg
     tokens = batch["tokens"]
     B, S = tokens.shape
@@ -1000,6 +1051,7 @@ def prefill(plan: ModelPlan, params, batch: dict, cache):
     x, new_cache, _ = _run_stack(
         plan, params["dec"], cfg.pattern, x,
         mode="prefill", pos_ids=pos, caches=cache, enc_out=enc_out,
+        valid_len=batch.get("length"),
     )
     x = apply_norm(params["final_norm"], x, cfg.norm)
     logits = _head_logits(x[:, -1:], _logit_head(plan, params))[:, 0]

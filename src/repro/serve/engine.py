@@ -135,7 +135,9 @@ class ServingEngine:
     executable serves all prompt lengths; the prompt's *last real token*
     is replayed as the first decode so padding never pollutes the
     distribution (pad positions remain invalid: each slot's validity mask
-    is its own position).
+    is its own position).  Recurrent state (mamba, short conv) would fold
+    the replayed token in twice and the pads after it, so the prefill is
+    told its length, the prompt before its last token, and stops there.
     """
 
     def __init__(
@@ -205,7 +207,8 @@ class ServingEngine:
             toks[0, :n] = req.prompt
             tmp_cache = init_cache(self.plan, 1, self.max_seq)
             _, tmp_cache = self._prefill(
-                self.params, {"tokens": jnp.asarray(toks)}, tmp_cache
+                self.params, {"tokens": jnp.asarray(toks), "length": jnp.int32(n - 1)},
+                tmp_cache,
             )
             self.n_prefills += 1
             self.n_prefill_tokens += n

@@ -60,6 +60,8 @@ def _linear_meta(plan: M.ModelPlan, name: str):
         "wx": (cfg.ssm_nheads * cfg.ssm_headdim, d, "ssm_fused", "embed"),
         "wbc": (2 * cfg.ssm_ngroups * cfg.ssm_state, d, None, "embed"),
         "out_proj": (d, cfg.ssm_nheads * cfg.ssm_headdim, None, "ssm_fused"),
+        "conv_in": (3 * d, d, None, "embed"),
+        "conv_out": (d, d, None, None),
         "w_gate": (cfg.moe_ff, d, "expert_ffn", "embed"),
         "w_up": (cfg.moe_ff, d, "expert_ffn", "embed"),
         "w_down": (d, cfg.moe_ff, None, "expert_ffn"),

@@ -171,3 +171,35 @@ def test_moe_dispatch_groups_equivalent(rng):
         plan = make_plan(cfg, 1, dispatch_groups=g)
         losses.append(float(train_loss(plan, params, batch)))
     assert abs(losses[0] - losses[1]) < 0.02  # capacity-local drops only
+
+
+@pytest.mark.parametrize("arch", ["mamba2_2_7b", "jamba_1_5_large", "lfm2_8b_a1b"])
+def test_padded_prefill_state_stops_at_length(arch):
+    """A right-padded prefill told its length leaves the recurrent state
+    (conv buffers, SSM state) the exact prefill of the real tokens leaves,
+    so the contiguous engine's padded buckets serve these stacks; and the
+    engine's greedy tokens equal the argmax of full forwards."""
+    from repro.serve.engine import Request, ServingEngine
+
+    cfg = reduce_cfg(get_config(arch))
+    plan = make_plan(cfg, 1)
+    params = init_params(plan, jax.random.PRNGKey(5))
+    toks = jnp.asarray(np.random.default_rng(5).integers(0, cfg.vocab, (1, 16)).astype(np.int32))
+    n = 11
+    _, exact = prefill(plan, params, {"tokens": toks[:, :n]}, init_cache(plan, 1, 32))
+    _, padded = prefill(plan, params, {"tokens": toks, "length": jnp.int32(n)},
+                        init_cache(plan, 1, 32))
+    for i, b in enumerate(cfg.pattern):
+        if b.kind == "attn":
+            continue
+        for got, want in zip(jax.tree.leaves(padded[f"b{i}"]), jax.tree.leaves(exact[f"b{i}"])):
+            np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32),
+                                       rtol=1e-5, atol=1e-5)
+    eng = ServingEngine(plan, params, max_batch=2, max_seq=32, prefill_pad=8)
+    prompt = np.asarray(toks[0, :n])
+    eng.submit(Request(rid=0, prompt=prompt, max_new_tokens=3))
+    seq = list(prompt)
+    for _ in range(3):
+        lg, _ = prefill(plan, params, {"tokens": jnp.asarray([seq])}, init_cache(plan, 1, 32))
+        seq.append(int(jnp.argmax(lg[0])))
+    assert eng.run()[0].output == seq[n:]

@@ -350,10 +350,10 @@ def test_solver_groups_outlier_layers(layer_problem):
     sig3 = jnp.stack([sigma, sigma])
     cfg = PTQConfig(method="qe_outlier", spec=SPEC3, iterations=3,
                     outlier_frac=0.01)
-    w_hat3, hs, grids = _solve_group(w3, sig3, cfg, mesh=None)
+    w_hat3, hs, grid3 = _solve_group(w3, sig3, cfg, mesh=None)
     assert w_hat3.shape == w3.shape
     assert len(hs) == G and all(h is not None for h in hs)
-    assert len(grids) == G and all(g is not None for g in grids)
+    assert grid3.scale.shape[0] == G and grid3.zero.shape[0] == G  # one grid per layer
     s = max(int(cfg.outlier_frac * w.size), 1)
     for g in range(G):
         assert int((np.asarray(hs[g]) != 0).sum()) <= s

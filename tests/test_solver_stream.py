@@ -142,7 +142,7 @@ def test_moe_vmapped_experts_match_per_expert_loop():
     p_blk = jax.tree.map(lambda a: a[0], params["dec"])["b0"]
     checked = 0
     for name in sorted(_MOE_NAMES & set(p_blk)):
-        st = stats[f"s/{name}"]
+        st = stats[f"s/{solver._SHARED_SIGMA.get(name, name)}"]
         w = p_blk[name]
         for e in range(w.shape[0]):
             w2d = w[e].reshape(w.shape[1], -1).T.astype(jnp.float32)

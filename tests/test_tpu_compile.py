@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +23,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.core.calib import CalibStats
 from repro.kernels import ops
 from repro.kernels import quantease_cd as qcd
 from repro.kernels.dequant_matmul import dequant_matmul_pallas, select_tile_k
@@ -162,7 +164,8 @@ def test_ptq_block_programs_compile(one_chip, monkeypatch):
     assert len(sigmas) == 7
     sigma_bytes = sum(4 * s.size for s in sigmas.values())
     assert sigma_bytes == 494_927_872
-    cap = solver._capture_program.lower(CFG, hp, b, None, p_blk, x, None, sigmas).compile()
+    acc = {k: CalibStats(s) for k, s in sigmas.items()}
+    cap = solver._capture_program.lower(CFG, hp, b, None, p_blk, x, None, acc).compile()
     cap_mem = cap.memory_analysis()
     assert cap_mem.alias_size_in_bytes >= sigma_bytes
 
@@ -183,3 +186,133 @@ def test_ptq_block_programs_compile(one_chip, monkeypatch):
     print(f"capture temp {cap_mem.temp_size_in_bytes} B, alias "
           f"{cap_mem.alias_size_in_bytes} B; recompute (packed 4-bit) temp "
           f"{rec.memory_analysis().temp_size_in_bytes} B")
+
+
+LFM2 = get_config("lfm2_8b_a1b")
+
+
+@pytest.mark.parametrize("layer", [2, 3], ids=["attn_moe", "conv_moe"])
+def test_ptq_lfm2_block_programs_compile(one_chip, monkeypatch, layer):
+    """The capture and recompute programs of an LFM2 routed-expert block at
+    its published widths, 8 × 2048 tokens a chunk: the capture's per-expert
+    Σ are aliased (one (E, d, d) Σ for the gate and up input, one (E, F, F)
+    for the down input), and the recompute over packed 4-bit experts runs
+    its grouped products as the TPU's own ragged dot."""
+    import dataclasses
+
+    from repro.core import solver
+    from repro.models import make_plan
+    from repro.models.model import param_shapes
+    from repro.quant import GridSpec, compute_grid
+
+    cfg_l = dataclasses.replace(LFM2, pattern=(LFM2.pattern[layer],))
+    plan = make_plan(cfg_l)
+    b, hp = cfg_l.pattern[0], plan.heads
+    p_blk = jax.tree.map(lambda a: _s(one_chip, a.shape[1:], a.dtype),
+                         param_shapes(plan)["dec"])["b0"]
+    x = _s(one_chip, (8, 2048, LFM2.d_model), jnp.bfloat16)
+    sig = solver._capture_program.eval_shape(cfg_l, hp, b, None, p_blk, x, None, None)
+    E, d, F = LFM2.n_experts, LFM2.d_model, LFM2.moe_ff
+    assert sig["w_gate"].sigma.shape == (E, d, d) and "w_up" not in sig
+    assert sig["w_down"].sigma.shape == (E, F, F) and sig["w_down"].n == 8 * 2048 * LFM2.top_k
+    acc = jax.tree.map(lambda a: _s(one_chip, a.shape, a.dtype), sig)
+    cap = solver._capture_program.lower(cfg_l, hp, b, None, p_blk, x, None, acc).compile()
+    cap_mem = cap.memory_analysis()
+    sigma_bytes = sum(4 * st.sigma.size for st in sig.values())
+    assert cap_mem.alias_size_in_bytes >= sigma_bytes
+    assert "ragged" in cap.as_text()
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)  # trace as on the chip
+    cfg = solver.PTQConfig(spec=GridSpec(bits=4), emit="qt")
+
+    def emit(w2d):
+        return solver._emit_leaf(w2d, None, None, cfg, compute_grid(w2d, cfg.spec))
+
+    qblk = dict(p_blk)
+    for name in solver.QUANTIZABLE & set(p_blk):
+        w = p_blk[name]
+        if name in solver._MOE_NAMES:
+            w2 = jax.ShapeDtypeStruct((w.shape[0], w.shape[2], w.shape[1]), jnp.float32)
+            qt = jax.eval_shape(jax.vmap(emit), w2)
+        else:
+            p_in = sig[solver._SHARED_SIGMA.get(name, name)].sigma.shape[-1]
+            qt = jax.eval_shape(emit, jax.ShapeDtypeStruct((w.size // p_in, p_in), jnp.float32))
+        assert qt.packed
+        qblk[name] = jax.tree.map(lambda a: _s(one_chip, a.shape, a.dtype), qt)
+    rec = solver._block_program.lower(cfg_l, hp, b, qblk, x, None).compile()
+    assert "ragged" in rec.as_text()
+    print(f"LFM2 layer {layer}: capture temp {cap_mem.temp_size_in_bytes} B, alias "
+          f"{cap_mem.alias_size_in_bytes} B; recompute (packed 4-bit) temp "
+          f"{rec.memory_analysis().temp_size_in_bytes} B")
+
+
+@pytest.mark.parametrize("G,q,p", [(32, 2 * LFM2.moe_ff, LFM2.d_model),
+                                   (32, LFM2.d_model, LFM2.moe_ff)],
+                         ids=["gate_up", "down"])
+def test_expert_group_solve_compiles(one_chip, monkeypatch, G, q, p):
+    """The grouped CD solves of an LFM2 expert layer as the solver runs
+    them: all 32 experts in one vmapped call, gate and up stacked along
+    their rows (one Σ each), on the fused kernel.  Together with the
+    model, the calibration set and the block's Σ they fit one v5e chip:
+    arguments, temporaries and output of the larger group take 9.5 GB
+    (PERF.md §4), so the solver runs each group whole."""
+    from repro.core import quantease
+    from repro.quant import GridSpec, compute_grid
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    spec = GridSpec(bits=4)
+    w = _s(one_chip, (G, q, p))
+    grid = jax.eval_shape(jax.vmap(lambda a: compute_grid(a, spec)), w)
+    grid = jax.tree.map(lambda a: _s(one_chip, a.shape, a.dtype), grid)
+    c = quantease.quantease_quantize.lower(
+        w, _s(one_chip, (G, p, p)), spec, grid=grid, iterations=25, percdamp=0.01,
+        use_kernel="auto").compile()
+    assert "tpu_custom_call" in c.as_text()
+    m = c.memory_analysis()
+    total = m.argument_size_in_bytes + m.temp_size_in_bytes + m.output_size_in_bytes
+    print(f"expert group ({G}, {q}, {p}): arguments {m.argument_size_in_bytes} B, "
+          f"temp {m.temp_size_in_bytes} B, output {m.output_size_in_bytes} B")
+    assert total < 10e9
+
+
+@pytest.fixture(scope="module")
+def v5e_mesh(one_chip):
+    """The described 2×2 v5e as a ("data", "model") mesh."""
+    import numpy as np
+    from jax.experimental import topologies
+
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    return jax.sharding.Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
+
+
+@pytest.mark.parametrize("parallel", ["ep", "tp"])
+@pytest.mark.parametrize("tokens", [(16, 1), (2, 512)], ids=["decode", "prefill"])
+def test_dropless_moe_keeps_expert_weights_in_place(v5e_mesh, parallel, tokens):
+    """Dropless MoE at OLMoE's widths (64 experts of 2048 → 1024, top-8) on a
+    2×2 mesh, dispatch groups on "data": with the experts (EP) or their ffn
+    (TP) on "model", no expert weight is all-gathered — the TPU's ragged
+    dot has no sharding rule of its own, and GSPMD would gather them."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.dist.sharding import axis_rules, make_rules
+    from repro.models.moe import moe_apply
+
+    E, d, f = 64, 2048, 1024
+    rules = make_rules(v5e_mesh, n_experts=E if parallel == "ep" else 0, moe_ff=f)
+    w_in = rules.sharding(("experts", "embed", "expert_ffn"))
+    w_out = rules.sharding(("experts", "expert_ffn", "embed"))
+    p = {"router": _s(NamedSharding(v5e_mesh, P()), (d, E)),
+         "w_gate": _s(w_in, (E, d, f), jnp.bfloat16), "w_up": _s(w_in, (E, d, f), jnp.bfloat16),
+         "w_down": _s(w_out, (E, f, d), jnp.bfloat16)}
+    x = _s(rules.sharding(("batch", None, None)), tokens + (d,), jnp.bfloat16)
+
+    def fn(p, x):
+        with axis_rules(rules):
+            return moe_apply(p, x, n_experts=E, top_k=8, act="silu", gated=True,
+                             norm_topk=False, dispatch_groups=2)[0]
+
+    text = jax.jit(fn).lower(p, x).compile().as_text()
+    gathered = [functools.reduce(lambda a, b: a * b, map(int, dims.split(",")), 1)
+                for dims in re.findall(r"= \w+\[([0-9,]+)\][^=\n]* all-gather(?:-start)?\(", text)]
+    assert max(gathered, default=0) < d * f // 2, gathered
